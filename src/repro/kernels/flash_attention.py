@@ -124,7 +124,7 @@ def _blocks_for(S, D, dtype, causal, window, q_block, kv_block):
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, q_block=None,
-                    kv_block=None, interpret=None):
+                    kv_block=None, interpret=False):
     """q: [B,H,S,D]; k,v: [B,K,S,D] (H % K == 0). Returns [B,H,S,D].
 
     D is zero-padded to a multiple of 128 (MXU lane width); softmax scale uses
@@ -133,8 +133,6 @@ def flash_attention(q, k, v, *, causal=True, window=None, q_block=None,
     B, H, S, D = q.shape
     K = k.shape[1]
     G = H // K
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     q_block, kv_block = _blocks_for(S, D, q.dtype, causal, window,
                                     q_block, kv_block)
     q_block = min(q_block, S)
@@ -180,7 +178,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, q_block=None,
 
 
 def tune(q, k, v, *, causal=True, window=None, trials=3,
-         candidates=BLOCK_CANDIDATES, interpret=None):
+         candidates=BLOCK_CANDIDATES, interpret=False):
     """Autotune (q_block, kv_block) for this call shape and persist the
     winner in the on-disk cache; returns the winning config."""
     B, H, S, D = q.shape

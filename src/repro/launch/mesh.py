@@ -7,17 +7,12 @@ jax device state. Single pod: 16x16 = 256 chips (TPU v5e pod slice); multi-pod:
 from __future__ import annotations
 
 import jax
-
-try:                                  # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:                   # older jax: meshes are Auto-only
-    AxisType = None
+from jax.sharding import AxisType
 
 
-def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+def _make_mesh(shape, axes, devices=None):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -26,9 +21,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     return _make_mesh(shape, axes)
 
 
-def make_host_mesh(shape=None, axes=None):
-    """Small mesh over whatever local devices exist (tests / smoke runs)."""
-    n = len(jax.devices())
+def make_host_mesh(shape=None, axes=None, devices=None):
+    """Small mesh over whatever local devices exist (tests / smoke runs), or
+    over ``devices`` when given — e.g. two of a host's four chips, the
+    target of an elastic restart onto fewer devices."""
+    n = len(jax.devices() if devices is None else devices)
     if shape is None:
         if n >= 8:
             shape, axes = (2, n // 2), ("data", "model")
@@ -36,4 +33,4 @@ def make_host_mesh(shape=None, axes=None):
             shape, axes = (1, n), ("data", "model")
         else:
             shape, axes = (1, 1), ("data", "model")
-    return _make_mesh(shape, axes)
+    return _make_mesh(shape, axes, devices)

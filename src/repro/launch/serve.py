@@ -31,7 +31,9 @@ def __getattr__(name):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serving.engine import Server
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-2b")
     ap.add_argument("--batch", type=int, default=4)

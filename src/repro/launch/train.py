@@ -27,6 +27,7 @@ from repro.core import Cluster
 from repro.core import runtime_state as RS
 from repro.core.restore import as_source, translation_plan
 from repro.data import DataPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import Model
 from repro.optim import make_optimizer, wsd
@@ -404,6 +405,7 @@ def main():
     ap.add_argument("--no-ram-tier", dest="ram_tier", action="store_false",
                     help="disk-only recovery (skip peer replication)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     ckpt_io = CkptIOConfig(codec=args.ckpt_codec,

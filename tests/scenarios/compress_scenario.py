@@ -4,6 +4,7 @@ tolerance per step, and the error-feedback residual keeps the ACCUMULATED
 reduction unbiased across steps."""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import sys
 

@@ -4,6 +4,7 @@ openmpi; verify the training trajectory continues bit-compatibly (modulo
 reduction-order noise from the new sharding)."""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import sys
 import tempfile

@@ -5,6 +5,7 @@ planner must store it exactly ONCE, and it must restore bit-identically on a
 DIFFERENT mesh shape (4, 2)."""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import sys
 

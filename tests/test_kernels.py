@@ -88,6 +88,28 @@ def test_ops_dispatch_uses_ref_on_cpu():
     np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("op", ["flash_attention", "decode_attention", "gla"])
+def test_forced_kernel_fails_loudly_off_tpu(op):
+    """Kernels default to interpret=False: forcing the Pallas path on the
+    CPU raises instead of silently running the interpreter."""
+    from repro.kernels import ops
+    B, H, K, S, D = 1, 2, 2, 32, 16
+    heads_first = jnp.zeros((B, H, S, D))       # flash layout
+    seq_first = jnp.zeros((B, S, K, D))         # decode cache / GLA layout
+    calls = {
+        "flash_attention": lambda: ops.flash_attention(
+            heads_first, heads_first, heads_first, force="kernel"),
+        "decode_attention": lambda: ops.decode_attention(
+            heads_first[:, :, 0], seq_first, seq_first, jnp.int32(S),
+            force="kernel"),
+        "gla": lambda: ops.gla(seq_first, seq_first, seq_first,
+                               jnp.zeros((B, S, K)), chunk=16,
+                               force="kernel"),
+    }
+    with pytest.raises(ValueError, match="interpret"):
+        calls[op]()
+
+
 # ---------------------------------------------------------------------------
 # paged decode (DMA-gathered KV pool via scalar-prefetch page table)
 # ---------------------------------------------------------------------------

@@ -150,3 +150,33 @@ def test_serve_restore_rewinds_generated_stream(tmp_path):
     assert len(srv.generated) == 3          # replayed tokens not duplicated
     srv.decode(2, srv.resume_tok)
     assert len(srv.generated) == 5
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_dir(monkeypatch, tmp_path, env_dir):
+    """The environment's cache directory wins and nothing else is set;
+    without it the cache goes to the fixed <repo>/.jax_cache."""
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = Path(__file__).resolve().parents[1] / ".jax_cache"
+    else:
+        want = tmp_path / env_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(want))
+    try:
+        assert compile_cache.enable_compile_cache() == str(want)
+        set_dir = jax.config.jax_compilation_cache_dir
+        assert set_dir == (before if env_dir else str(want))
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_host_mesh_over_given_devices():
+    """make_host_mesh(devices=...) spans exactly the devices it is given
+    (the target of an elastic restart onto fewer chips)."""
+    from repro.launch.mesh import make_host_mesh
+    devs = jax.devices()[:1]
+    mesh = make_host_mesh(devices=devs)
+    assert list(mesh.devices.flat) == devs
+    assert dict(mesh.shape) == {"data": 1, "model": 1}
