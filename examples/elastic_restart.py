@@ -44,7 +44,8 @@ def main():
         t = small.restart_timings
         print(f"restored on {len(small.cluster.ranks)} ranks "
               f"under {small.cluster.backend_name} at step {small.step} "
-              f"(rebind {t['rebind_ms']:.1f}ms / arrays {t['arrays_ms']:.1f}ms,"
+              f"(rebind {t['rebind_ms']:.1f}ms / arrays {t['arrays_ms']:.1f}ms"
+              f" [read {t['read_ms']:.1f}ms, place {t['place_ms']:.1f}ms],"
               f" total {t['total_ms']:.1f}ms)")
         small.run(20, log_every=10)
         small.pipeline.stop()

@@ -37,7 +37,8 @@ def main():
         t = tr.restart_timings
         print(f"final backend: {tr.cluster.backend_name} "
               f"(restarts: {tr.cluster.restart_count}; last restart: "
-              f"rebind {t['rebind_ms']:.1f}ms / arrays {t['arrays_ms']:.1f}ms,"
+              f"rebind {t['rebind_ms']:.1f}ms / arrays {t['arrays_ms']:.1f}ms"
+              f" [read {t['read_ms']:.1f}ms, place {t['place_ms']:.1f}ms],"
               f" total {t['total_ms']:.1f}ms)")
         assert tr.cluster.backend_name == "openmpi"
         assert tr.cluster.restart_count == 1

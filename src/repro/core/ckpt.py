@@ -27,13 +27,13 @@ from __future__ import annotations
 import json
 import shutil
 import threading
-import time
 from pathlib import Path
 
 import jax
 import numpy as np
 
 from repro.core import ckpt_io, ckpt_pipeline
+from repro.core.tracing import span
 
 
 def snapshot_shards(tree, world_size, mesh):
@@ -236,7 +236,7 @@ class CheckpointWriter:
         writers: dict[int, ckpt_io.RankShardWriter] = {}
         wlock = threading.Lock()
         per_rank = {r: {"keys": [], "digests": {}, "fresh": set(),
-                        "raw_bytes": 0, "seconds": 0.0,
+                        "raw_bytes": 0, "write_ms": 0.0,
                         "lock": threading.Lock()}
                     for r in range(self.world_size)}
 
@@ -252,41 +252,42 @@ class CheckpointWriter:
         def sink(rank, its, views):
             """Consume one landed batch: per-shard delta decision + append
             into the rank's shard container.  Runs on pool threads."""
-            t1 = time.perf_counter()
-            w = _writer_for(rank)
-            out = []
-            for it, view in zip(its, views):
-                digest, fresh = None, True
-                if self.incremental:
-                    if lossy or not full:
-                        digest = ckpt_io.shard_digest(view)
-                    if not full:
-                        prev = self._digest_table.get(
-                            f"{rank}:{it.key}", {}).get("digest")
-                        fresh = prev != digest
-                if fresh:
-                    digest = w.add(it.key, view, digest=digest,
-                                   compute_digest=self.incremental
-                                   and not lossy,
-                                   kind="runtime"
-                                   if int(it.key.split(".", 1)[0]) in rt_leaves
-                                   else "array")
-                out.append((it, digest, fresh))
             pr = per_rank[rank]
-            with pr["lock"]:
-                for it, digest, fresh in out:
-                    pr["keys"].append(it.key)
-                    pr["raw_bytes"] += it.nbytes
-                    if digest is not None:
-                        pr["digests"][it.key] = digest
+            with span("ckpt.sink", into=pr, key="write_ms", add=True,
+                      step=step, rank=rank,
+                      bytes=sum(it.nbytes for it in its)):
+                w = _writer_for(rank)
+                out = []
+                for it, view in zip(its, views):
+                    digest, fresh = None, True
+                    if self.incremental:
+                        if lossy or not full:
+                            digest = ckpt_io.shard_digest(view)
+                        if not full:
+                            prev = self._digest_table.get(
+                                f"{rank}:{it.key}", {}).get("digest")
+                            fresh = prev != digest
                     if fresh:
-                        pr["fresh"].add(it.key)
-                pr["seconds"] += time.perf_counter() - t1
+                        digest = w.add(
+                            it.key, view, digest=digest,
+                            compute_digest=self.incremental and not lossy,
+                            kind="runtime"
+                            if int(it.key.split(".", 1)[0]) in rt_leaves
+                            else "array")
+                    out.append((it, digest, fresh))
+                with pr["lock"]:
+                    for it, digest, fresh in out:
+                        pr["keys"].append(it.key)
+                        pr["raw_bytes"] += it.nbytes
+                        if digest is not None:
+                            pr["digests"][it.key] = digest
+                        if fresh:
+                            pr["fresh"].add(it.key)
 
         pipe = ckpt_pipeline.SnapshotPipeline(
             pool, batch_bytes=self.snapshot_batch_bytes, arenas=self._arenas)
         try:
-            res = pipe.run(items, sink)
+            res = pipe.run(items, sink, step=step)
         except BaseException as e:       # noqa: BLE001 — incl. injected faults
             # a fault mid-snapshot (e.g. the ckpt.snapshot_batch failpoint)
             # must not leave the writer wedged: run() has already drained the
@@ -300,40 +301,41 @@ class CheckpointWriter:
             raise
         req.timings["snapshot_ms"] = res["snapshot_ms"]
         req.timings["enqueue_ms"] = res["enqueue_ms"]
-        req.write_stats["device_to_host_s"] = round(
-            res["snapshot_ms"] / 1e3, 4)
         req.write_stats["snapshot_batches"] = res["batches"]
 
         def _finalize():
             try:
-                t_write = time.time()
-                first_err = None
-                for f in res["futures"]:
-                    try:
-                        f.result()
-                    except BaseException as e:  # noqa: BLE001
-                        if first_err is None:
-                            first_err = e
-                if first_err is not None:
-                    raise first_err
-                # stable once every sink future has resolved
-                req.write_stats["arena_spills"] = res["counters"]["spills"]
-                results = []
-                for r in range(self.world_size):
-                    st = _writer_for(r).finish()   # ranks w/o shards: empty
-                    ckpt_io.atomic_write_text(
-                        tdir / f"rank{r:05d}" / "state.json",
-                        json.dumps(rank_states.get(r, {})))
-                    pr = per_rank[r]
-                    results.append({"rank": r, "keys": pr["keys"],
-                                    "digests": pr["digests"],
-                                    "fresh": pr["fresh"],
-                                    "enc_bytes": st["enc_bytes"],
-                                    "fresh_raw_bytes": st["raw_bytes"],
-                                    "raw_bytes": pr["raw_bytes"],
-                                    "seconds": round(pr["seconds"], 4)})
-                self._publish(step, mesh, leaves_meta, results, full,
-                              extra_meta, tdir, fdir, req, t_write)
+                with span("ckpt.persist", into=req.timings, key="persist_ms",
+                          step=step):
+                    first_err = None
+                    for f in res["futures"]:
+                        try:
+                            f.result()
+                        except BaseException as e:  # noqa: BLE001
+                            if first_err is None:
+                                first_err = e
+                    if first_err is not None:
+                        raise first_err
+                    # stable once every sink future has resolved
+                    req.write_stats["arena_spills"] = res["counters"]["spills"]
+                    results = []
+                    for r in range(self.world_size):
+                        st = _writer_for(r).finish()  # ranks w/o shards: empty
+                        ckpt_io.atomic_write_text(
+                            tdir / f"rank{r:05d}" / "state.json",
+                            json.dumps(rank_states.get(r, {})))
+                        pr = per_rank[r]
+                        results.append({"rank": r, "keys": pr["keys"],
+                                        "digests": pr["digests"],
+                                        "fresh": pr["fresh"],
+                                        "enc_bytes": st["enc_bytes"],
+                                        "fresh_raw_bytes": st["raw_bytes"],
+                                        "raw_bytes": pr["raw_bytes"],
+                                        "seconds": round(
+                                            pr["write_ms"] / 1e3, 4)})
+                    self._publish(step, mesh, leaves_meta, results, full,
+                                  extra_meta, tdir, fdir, req)
+                self._after_commit(req, fdir)
             except Exception as e:  # noqa: BLE001
                 req.error = e
                 for w in writers.values():
@@ -351,62 +353,65 @@ class CheckpointWriter:
     def _checkpoint_buffered(self, step, arrays, mesh, rank_states,
                              extra_meta, tdir, fdir, full, req,
                              rt_leaves=frozenset()):
-        t0 = time.time()
-        leaves_meta, per_rank = snapshot_shards(arrays, self.world_size, mesh)
+        with span("ckpt.snapshot", into=req.timings, key="snapshot_ms",
+                  step=step):
+            leaves_meta, per_rank = snapshot_shards(arrays, self.world_size,
+                                                    mesh)
         for li in rt_leaves:
             leaves_meta[li]["kind"] = "runtime"
-        snap_s = time.time() - t0
-        req.write_stats["device_to_host_s"] = round(snap_s, 4)
-        req.timings["snapshot_ms"] = round(snap_s * 1e3, 3)
         req.timings["enqueue_ms"] = 0.0
 
         def _write_rank(rank: int):
-            t1 = time.time()
             rdir = tdir / f"rank{rank:05d}"
             arrays_r = per_rank.get(rank, {})
-            # digests exist to detect clean shards; a non-incremental writer
-            # rewrites everything anyway, so skip hashing entirely.  On a
-            # full lossless checkpoint the hash is FUSED into the write
-            # stream (one memory pass); only delta decisions and lossy
-            # codecs need a separate pre-pass.
-            lossy = self.codec.lossy
-            if self.incremental and (lossy or not full):
-                digests = {k: ckpt_io.shard_digest(a)
-                           for k, a in arrays_r.items()}
-            else:
-                digests = {}
-            if full:
-                fresh_keys = set(arrays_r)
-            else:
-                fresh_keys = {
-                    k for k in arrays_r
-                    if self._digest_table.get(f"{rank}:{k}", {}).get("digest")
-                    != digests[k]}
-            st = ckpt_io.write_rank_shards(
-                rdir, {k: arrays_r[k] for k in arrays_r if k in fresh_keys},
-                self.codec, self.chunk_bytes,
-                digests={k: digests[k] for k in fresh_keys & digests.keys()},
-                compute_digests=self.incremental and not lossy,
-                kinds={k: "runtime" for k in fresh_keys
-                       if int(k.split(".", 1)[0]) in rt_leaves})
-            ckpt_io.atomic_write_text(rdir / "state.json",
-                                      json.dumps(rank_states.get(rank, {})))
             raw_all = sum(a.nbytes for a in arrays_r.values())
+            with span("ckpt.sink", step=step, rank=rank, bytes=raw_all) as sp:
+                # digests exist to detect clean shards; a non-incremental
+                # writer rewrites everything anyway, so skip hashing
+                # entirely.  On a full lossless checkpoint the hash is FUSED
+                # into the write stream (one memory pass); only delta
+                # decisions and lossy codecs need a separate pre-pass.
+                lossy = self.codec.lossy
+                if self.incremental and (lossy or not full):
+                    digests = {k: ckpt_io.shard_digest(a)
+                               for k, a in arrays_r.items()}
+                else:
+                    digests = {}
+                if full:
+                    fresh_keys = set(arrays_r)
+                else:
+                    fresh_keys = {
+                        k for k in arrays_r
+                        if self._digest_table.get(f"{rank}:{k}", {}).get(
+                            "digest") != digests[k]}
+                st = ckpt_io.write_rank_shards(
+                    rdir, {k: arrays_r[k] for k in arrays_r
+                           if k in fresh_keys},
+                    self.codec, self.chunk_bytes,
+                    digests={k: digests[k]
+                             for k in fresh_keys & digests.keys()},
+                    compute_digests=self.incremental and not lossy,
+                    kinds={k: "runtime" for k in fresh_keys
+                           if int(k.split(".", 1)[0]) in rt_leaves})
+                ckpt_io.atomic_write_text(
+                    rdir / "state.json", json.dumps(rank_states.get(rank, {})))
             return {"rank": rank, "keys": list(arrays_r),
                     "digests": {**digests, **st["digests"]},
                     "fresh": fresh_keys,
                     "enc_bytes": st["enc_bytes"],
                     "fresh_raw_bytes": st["raw_bytes"],
                     "raw_bytes": raw_all,
-                    "seconds": round(time.time() - t1, 4)}
+                    "seconds": round(sp.ms / 1e3, 4)}
 
         def _write():
             try:
-                t_write = time.time()
-                results = self._get_pool().map(_write_rank,
-                                               range(self.world_size))
-                self._publish(step, mesh, leaves_meta, results, full,
-                              extra_meta, tdir, fdir, req, t_write)
+                with span("ckpt.persist", into=req.timings, key="persist_ms",
+                          step=step):
+                    results = self._get_pool().map(_write_rank,
+                                                   range(self.world_size))
+                    self._publish(step, mesh, leaves_meta, results, full,
+                                  extra_meta, tdir, fdir, req)
+                self._after_commit(req, fdir)
             except Exception as e:  # noqa: BLE001
                 req.error = e
             finally:
@@ -416,10 +421,11 @@ class CheckpointWriter:
 
     # -- shared publish tail -------------------------------------------------
     def _publish(self, step, mesh, leaves_meta, results, full, extra_meta,
-                 tdir, fdir, req, t_write):
+                 tdir, fdir, req):
         """Resolve shard locations, assemble the manifest, COMMIT, atomically
-        publish, roll the digest table forward, GC.  Runs on the background
-        writer/finalize thread for both snapshot paths."""
+        publish, roll the digest table forward.  Runs on the background
+        writer/finalize thread for both snapshot paths, inside the
+        ``ckpt.persist`` span; :meth:`_after_commit` follows it."""
         new_table: dict[str, dict] = {}
         src: dict[tuple, dict] = {}
         for r in results:
@@ -474,13 +480,14 @@ class CheckpointWriter:
         tdir.rename(fdir)       # atomic publish
         self._digest_table = new_table
         self._since_full = 1 if full else self._since_full + 1
-        persist_s = time.time() - t_write
-        req.timings["persist_ms"] = round(persist_s * 1e3, 3)
         req.write_stats.update(
             bytes_total=total, bytes_written=written, full=full,
             fresh_shards=fresh_shards, total_shards=total_shards,
-            write_s=round(persist_s, 4),
             per_rank_write_s=per_rank_s)
+
+    def _after_commit(self, req, fdir):
+        """After the ``ckpt.persist`` span: GC, then the commit hook."""
+        req.write_stats["write_s"] = round(req.timings["persist_ms"] / 1e3, 4)
         self._gc()
         cb = self.on_commit
         if cb is not None:

@@ -40,6 +40,7 @@ import numpy as np
 
 from repro.core import ckpt_io
 from repro.core.faults import failpoint
+from repro.core.tracing import span
 
 DEFAULT_BATCH_MB = 8.0
 _MIN_BATCH_BYTES = 64 << 10
@@ -183,10 +184,12 @@ def _spill(hosts: list) -> list:
 class SnapshotPipeline:
     """Drives one pipelined snapshot over a writer pool.
 
-    ``run(items, sink)`` feeds rank-aligned batches through D2H into arena
-    (or spill) buffers and submits ``sink(rank, batch_items, host_views)``
-    to the pool for each batch; it returns as soon as the last batch is
-    enqueued, with the futures plus a timing/stat breakdown and a
+    ``run(items, sink, step=...)`` feeds rank-aligned batches through D2H
+    into arena (or spill) buffers and submits ``sink(rank, batch_items,
+    host_views)`` to the pool for each batch; it returns as soon as the
+    last batch is enqueued, with the futures plus a timing/stat breakdown
+    (``snapshot_ms`` / ``enqueue_ms``: the summed ``ckpt.d2h`` /
+    ``ckpt.enqueue`` spans of the batches, tagged with ``step``) and a
     ``release`` callable the caller MUST invoke once its blocking window
     closes (sinks hold until then; a 60 s backstop prevents a forgotten
     release from wedging the pool).  The sink is called on pool threads —
@@ -200,7 +203,7 @@ class SnapshotPipeline:
         self.arenas = arenas if arenas is not None else (HostArena(),
                                                          HostArena())
 
-    def run(self, items, sink: Callable) -> dict:
+    def run(self, items, sink: Callable, *, step: int | None = None) -> dict:
         batches = batch_plan(items, self.batch_bytes)
         # kick off D2H for EVERY batch up front: on accelerators the copies
         # overlap each other and run while earlier batches are being
@@ -241,15 +244,16 @@ class SnapshotPipeline:
             return None
 
         futures = []
-        t_get = t_submit = 0.0
+        timings = {"snapshot_ms": 0.0, "enqueue_ms": 0.0}
         try:
             for bi, (rank, its) in enumerate(batches):
                 # chaos-harness injection site: a raise here fails the
                 # checkpoint INSIDE its blocking window, mid-batch
                 failpoint("ckpt.snapshot_batch", rank=rank, batch=bi)
-                t0 = time.perf_counter()
-                hosts = jax.device_get([it.data for it in its])
-                t_get += time.perf_counter() - t0
+                with span("ckpt.d2h", into=timings, key="snapshot_ms",
+                          add=True, step=step, batch=bi, rank=rank,
+                          bytes=sum(it.nbytes for it in its)):
+                    hosts = jax.device_get([it.data for it in its])
 
                 def task(rank=rank, its=its, hosts=hosts):
                     window_closed.wait(timeout=60.0)
@@ -266,9 +270,9 @@ class SnapshotPipeline:
                         if arena is not None:
                             arena.release()
 
-                t0 = time.perf_counter()
-                futures.append(self.pool.submit(task))
-                t_submit += time.perf_counter() - t0
+                with span("ckpt.enqueue", into=timings, key="enqueue_ms",
+                          add=True, step=step, batch=bi):
+                    futures.append(self.pool.submit(task))
         except BaseException:
             # fail CLEAN: open the floodgates so already-enqueued sinks don't
             # camp on the 60 s backstop, and drain them so the caller can
@@ -286,6 +290,4 @@ class SnapshotPipeline:
         return {"futures": futures,
                 "release": window_closed.set,
                 "batches": len(batches),
-                "counters": counters,
-                "snapshot_ms": round(t_get * 1e3, 3),
-                "enqueue_ms": round(t_submit * 1e3, 3)}
+                "counters": counters, **timings}

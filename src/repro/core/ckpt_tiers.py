@@ -35,11 +35,11 @@ from __future__ import annotations
 import hashlib
 import json
 import threading
-import time
 from pathlib import Path
 
 from repro.core import ckpt_io
 from repro.core.callspec import TAG_BASES, coll_tag, handle_vid
+from repro.core.tracing import span
 
 __all__ = ["Container", "ReplicaTier", "TierImage", "TierVerifyError",
            "ring_partner", "container_sha"]
@@ -179,81 +179,80 @@ class ReplicaTier:
         """Load the committed image's per-rank containers and ring-push each
         over the interposed p2p layer, so after this returns every container
         exists in TWO ranks' memory (primary + partner replica)."""
-        t0 = time.perf_counter()
-        step_dir = Path(step_dir)
-        manifest = json.loads((step_dir / "manifest.json").read_text())
-        step = manifest["step"]
-        ws = manifest["world_size"]
-        alive = [r for r in cluster.survivors() if r < ws]
-        owned: dict[int, Container] = {}
-        for r in alive:
-            rdir = step_dir / f"rank{r:05d}"
-            data = (rdir / ckpt_io.BIN_NAME).read_bytes()
-            owned[r] = Container(step, r, ckpt_io.read_rank_index(rdir),
-                                 data, (rdir / "state.json").read_text(),
-                                 container_sha(data))
-        # dead-slot inheritance: after a live shrink the slot space still
-        # contains departed ranks whose committed containers nobody's RAM
-        # would otherwise hold — their ring successor reads them off the
-        # fresh commit so the RAM image stays complete over range(ws)
-        inherited: dict[int, list[Container]] = {}
-        for r in range(ws):
-            if r in alive:
-                continue
-            h = ring_partner(r, alive)
-            rdir = step_dir / f"rank{r:05d}"
-            if h is None or not rdir.is_dir():
-                continue
-            data = (rdir / ckpt_io.BIN_NAME).read_bytes()
-            inherited.setdefault(h, []).append(
-                Container(step, r, ckpt_io.read_rank_index(rdir), data,
-                          (rdir / "state.json").read_text(),
-                          container_sha(data)))
-        # send first, then receive: fabric sends enqueue without blocking,
-        # and consuming each push before returning keeps replica traffic
-        # out of any later drain's in-flight accounting
-        pushes = []
-        if len(alive) > 1:
+        with span("tier.replicate", into=self.stats, key="push_ms_total",
+                  add=True):
+            step_dir = Path(step_dir)
+            manifest = json.loads((step_dir / "manifest.json").read_text())
+            step = manifest["step"]
+            ws = manifest["world_size"]
+            alive = [r for r in cluster.survivors() if r < ws]
+            owned: dict[int, Container] = {}
             for r in alive:
-                p = ring_partner(r, alive)
-                m = cluster.mana(r)
-                c = owned[r]
-                m.backend.send(p, coll_tag("replica",
-                                           handle_vid(m.comm_world())),
-                               {"step": c.step, "rank": c.rank,
-                                "index": c.index, "data": c.data,
-                                "state": c.state, "sha": c.sha})
-                pushes.append((r, p))
-        received: dict[int, Container] = {}
-        for r, p in pushes:
-            pm = cluster.mana(p)
-            msg = pm._recv_any(r, coll_tag("replica",
-                                           handle_vid(pm.comm_world())))
-            received[p] = Container(msg["step"], msg["rank"], msg["index"],
-                                    msg["data"], msg["state"], msg["sha"])
-        with self._lock:
-            for r, c in owned.items():
-                self.stores.setdefault(r, {})[(step, r)] = c
-            for p, c in received.items():
-                self.stores.setdefault(p, {})[(step, c.rank)] = c
-            for h, cs in inherited.items():
-                for c in cs:
-                    self.stores.setdefault(h, {})[(step, c.rank)] = c
-            self.manifests[step] = manifest
-            self.newest_step = step
-            # retention: the newest step plus every base step its delta
-            # chain references — older steps' copies are dead weight
-            keep = {step, *manifest.get("base_steps", [])}
-            for store in self.stores.values():
-                for key in [k for k in store if k[0] not in keep]:
-                    del store[key]
-            self.manifests = {s: m for s, m in self.manifests.items()
-                              if s in keep}
-            self.stats["replicated_steps"] += 1
-            self.stats["pushed_bytes"] += sum(len(c.data)
-                                              for c in owned.values())
-            self.stats["push_ms_total"] += round(
-                (time.perf_counter() - t0) * 1e3, 3)
+                rdir = step_dir / f"rank{r:05d}"
+                data = (rdir / ckpt_io.BIN_NAME).read_bytes()
+                owned[r] = Container(step, r, ckpt_io.read_rank_index(rdir),
+                                     data, (rdir / "state.json").read_text(),
+                                     container_sha(data))
+            # dead-slot inheritance: after a live shrink the slot space still
+            # contains departed ranks whose committed containers nobody's RAM
+            # would otherwise hold — their ring successor reads them off the
+            # fresh commit so the RAM image stays complete over range(ws)
+            inherited: dict[int, list[Container]] = {}
+            for r in range(ws):
+                if r in alive:
+                    continue
+                h = ring_partner(r, alive)
+                rdir = step_dir / f"rank{r:05d}"
+                if h is None or not rdir.is_dir():
+                    continue
+                data = (rdir / ckpt_io.BIN_NAME).read_bytes()
+                inherited.setdefault(h, []).append(
+                    Container(step, r, ckpt_io.read_rank_index(rdir), data,
+                              (rdir / "state.json").read_text(),
+                              container_sha(data)))
+            # send first, then receive: fabric sends enqueue without blocking,
+            # and consuming each push before returning keeps replica traffic
+            # out of any later drain's in-flight accounting
+            pushes = []
+            if len(alive) > 1:
+                for r in alive:
+                    p = ring_partner(r, alive)
+                    m = cluster.mana(r)
+                    c = owned[r]
+                    m.backend.send(p, coll_tag("replica",
+                                               handle_vid(m.comm_world())),
+                                   {"step": c.step, "rank": c.rank,
+                                    "index": c.index, "data": c.data,
+                                    "state": c.state, "sha": c.sha})
+                    pushes.append((r, p))
+            received: dict[int, Container] = {}
+            for r, p in pushes:
+                pm = cluster.mana(p)
+                msg = pm._recv_any(r, coll_tag("replica",
+                                               handle_vid(pm.comm_world())))
+                received[p] = Container(msg["step"], msg["rank"], msg["index"],
+                                        msg["data"], msg["state"], msg["sha"])
+            with self._lock:
+                for r, c in owned.items():
+                    self.stores.setdefault(r, {})[(step, r)] = c
+                for p, c in received.items():
+                    self.stores.setdefault(p, {})[(step, c.rank)] = c
+                for h, cs in inherited.items():
+                    for c in cs:
+                        self.stores.setdefault(h, {})[(step, c.rank)] = c
+                self.manifests[step] = manifest
+                self.newest_step = step
+                # retention: the newest step plus every base step its delta
+                # chain references — older steps' copies are dead weight
+                keep = {step, *manifest.get("base_steps", [])}
+                for store in self.stores.values():
+                    for key in [k for k in store if k[0] not in keep]:
+                        del store[key]
+                self.manifests = {s: m for s, m in self.manifests.items()
+                                  if s in keep}
+                self.stats["replicated_steps"] += 1
+                self.stats["pushed_bytes"] += sum(len(c.data)
+                                                  for c in owned.values())
 
     # -- recovery-side assembly ---------------------------------------------
     def image(self, cluster) -> "TierImage | None":
@@ -302,44 +301,43 @@ class ReplicaTier:
         without waiting for the next commit.  Containers with zero alive
         copies are unrecoverable here (that is the disk tier's job).
         Returns ``{"repushed": n, "single_copy": m}``."""
-        t0 = time.perf_counter()
-        with self._lock:
-            steps = sorted(self.manifests)
-            alive = sorted(cluster.survivors())
-            holders = {r: dict(self.stores.get(r, {})) for r in alive}
-        repushed = single = 0
-        if len(alive) < 2:
-            return {"repushed": 0,
-                    "single_copy": sum(len(s) for s in holders.values())}
-        for step in steps:
-            keys = sorted({k for st in holders.values()
-                           for k in st if k[0] == step})
-            for key in keys:
-                copies = [h for h in alive if key in holders[h]]
-                if len(copies) >= 2:
-                    continue
-                single += 1
-                src = copies[0]
-                dst = ring_partner(src, alive)
-                c = holders[src][key]
-                m, pm = cluster.mana(src), cluster.mana(dst)
-                m.backend.send(dst, coll_tag("replica",
-                                             handle_vid(m.comm_world())),
-                               {"step": c.step, "rank": c.rank,
-                                "index": c.index, "data": c.data,
-                                "state": c.state, "sha": c.sha})
-                msg = pm._recv_any(src, coll_tag("replica",
-                                                 handle_vid(pm.comm_world())))
-                rc = Container(msg["step"], msg["rank"], msg["index"],
-                               msg["data"], msg["state"], msg["sha"])
-                holders[dst][key] = rc
-                with self._lock:
-                    self.stores.setdefault(dst, {})[key] = rc
-                self.stats["pushed_bytes"] += len(rc.data)
-                repushed += 1
-        self.stats["push_ms_total"] += round(
-            (time.perf_counter() - t0) * 1e3, 3)
-        return {"repushed": repushed, "single_copy": single}
+        with span("tier.repair", into=self.stats, key="push_ms_total",
+                  add=True):
+            with self._lock:
+                steps = sorted(self.manifests)
+                alive = sorted(cluster.survivors())
+                holders = {r: dict(self.stores.get(r, {})) for r in alive}
+            repushed = single = 0
+            if len(alive) < 2:
+                return {"repushed": 0,
+                        "single_copy": sum(len(s) for s in holders.values())}
+            for step in steps:
+                keys = sorted({k for st in holders.values()
+                               for k in st if k[0] == step})
+                for key in keys:
+                    copies = [h for h in alive if key in holders[h]]
+                    if len(copies) >= 2:
+                        continue
+                    single += 1
+                    src = copies[0]
+                    dst = ring_partner(src, alive)
+                    c = holders[src][key]
+                    m, pm = cluster.mana(src), cluster.mana(dst)
+                    m.backend.send(dst, coll_tag("replica",
+                                                 handle_vid(m.comm_world())),
+                                   {"step": c.step, "rank": c.rank,
+                                    "index": c.index, "data": c.data,
+                                    "state": c.state, "sha": c.sha})
+                    msg = pm._recv_any(src, coll_tag(
+                        "replica", handle_vid(pm.comm_world())))
+                    rc = Container(msg["step"], msg["rank"], msg["index"],
+                                   msg["data"], msg["state"], msg["sha"])
+                    holders[dst][key] = rc
+                    with self._lock:
+                        self.stores.setdefault(dst, {})[key] = rc
+                    self.stats["pushed_bytes"] += len(rc.data)
+                    repushed += 1
+            return {"repushed": repushed, "single_copy": single}
 
     def reset(self) -> None:
         """Drop everything — called after a recovery: the restored world's

@@ -19,6 +19,7 @@ from repro.core.backends.fabric import Fabric
 from repro.core.ckpt import CheckpointWriter
 from repro.core.drain import drain_world, drain_world_legacy
 from repro.core.interpose import Mana
+from repro.core.tracing import span
 
 
 @dataclass
@@ -67,6 +68,9 @@ class Cluster:
         self.restart_timings: dict = {}
         self.rebind_stats: list = []
         self.restored_arrays = None
+        #: "<ckpt step>@<restart_count>" of the restore that built this
+        #: cluster: the ``restore`` arg of its spans
+        self.restore_id: Optional[str] = None
 
     @property
     def manas(self):
@@ -254,39 +258,44 @@ class Cluster:
     def checkpoint(self, step: int, arrays, mesh, extra_rank_state=None):
         """Drain -> barrier -> pipelined snapshot -> async write.  Returns
         the request; ``req.timings`` carries the stop-the-world breakdown
-        {drain_ms, snapshot_ms, enqueue_ms, blocking_ms} in milliseconds
-        (persist_ms lands once the background write commits)."""
+        {drain_ms, rank_state_ms, snapshot_ms, enqueue_ms, blocking_ms} in
+        milliseconds (persist_ms lands once the background write commits),
+        each filled by the ``ckpt.*`` span of that phase."""
         if self.writer is None:
             raise RuntimeError("no ckpt_dir configured")
-        t0 = time.perf_counter()
-        if self.ckpt_io.pipeline:
-            drain_stats = drain_world(self.manas,
-                                      timeout=self.ckpt_io.drain_timeout,
-                                      backoff=self.ckpt_io.drain_backoff)
-        else:
-            # pipeline=False selects the WHOLE PR 1 stop-the-world path for
-            # A/B measurement: spawn-per-checkpoint drain + buffered snapshot
-            drain_stats = drain_world_legacy(self.manas)
-        drain_ms = (time.perf_counter() - t0) * 1e3
-        rank_states = {}
-        for i, r in enumerate(self.ranks):
-            if not r.alive:
-                continue
-            # drain stats are keyed by RANK ID — with dead ranks a positional
-            # lookup would attach a survivor's stats to the wrong rank
-            st = {"mana": r.mana.snapshot(),
-                  "drain": drain_stats.get(r.mana.rank, {})}
-            if extra_rank_state:
-                st.update(extra_rank_state(i))
-            rank_states[i] = st
-        req = self.writer.checkpoint(step, arrays, mesh, rank_states,
-                                     extra_meta={"backend": self.backend_name,
-                                                 "members": self.survivors()},
-                                     defer_release=True)
+        timings = {}
+        with span("ckpt.blocking", into=timings, key="blocking_ms", step=step):
+            with span("ckpt.drain", into=timings, key="drain_ms", step=step):
+                if self.ckpt_io.pipeline:
+                    drain_stats = drain_world(
+                        self.manas, timeout=self.ckpt_io.drain_timeout,
+                        backoff=self.ckpt_io.drain_backoff)
+                else:
+                    # pipeline=False selects the WHOLE legacy
+                    # stop-the-world path for A/B measurement:
+                    # spawn-per-checkpoint drain + buffered snapshot
+                    drain_stats = drain_world_legacy(self.manas)
+            rank_states = {}
+            with span("ckpt.rank_state", into=timings, key="rank_state_ms",
+                      step=step):
+                for i, r in enumerate(self.ranks):
+                    if not r.alive:
+                        continue
+                    # drain stats are keyed by RANK ID — with dead ranks a
+                    # positional lookup would attach a survivor's stats to
+                    # the wrong rank
+                    st = {"mana": r.mana.snapshot(),
+                          "drain": drain_stats.get(r.mana.rank, {})}
+                    if extra_rank_state:
+                        st.update(extra_rank_state(i))
+                    rank_states[i] = st
+            req = self.writer.checkpoint(
+                step, arrays, mesh, rank_states,
+                extra_meta={"backend": self.backend_name,
+                            "members": self.survivors()},
+                defer_release=True)
         try:
-            req.timings["drain_ms"] = round(drain_ms, 3)
-            req.timings["blocking_ms"] = round(
-                (time.perf_counter() - t0) * 1e3, 3)
+            req.timings.update(timings)
             self.events.append(("checkpoint", step, time.time()))
         finally:
             # the blocking window ends HERE: only now may the held encode/
@@ -313,25 +322,49 @@ class Cluster:
         pool, and the result lands in ``fresh.restored_arrays``.
 
         The returned cluster carries phase timings mirroring
-        ``checkpoint``'s ``req.timings``: ``fresh.restart_timings`` =
-        {manifest_ms, lower_half_ms, rebind_ms, arrays_ms, total_ms} plus
-        per-rank rebind stats in ``fresh.rebind_stats``.  ``parallel=False``
-        selects the sequential seed-equivalent path (A/B baseline for
-        benchmarks/bench_restart.py)."""
+        ``checkpoint``'s ``req.timings``, each filled by the ``restore.*``
+        span of that phase: ``fresh.restart_timings`` = {manifest_ms,
+        lower_half_ms, rebind_ms, arrays_ms, total_ms}, where ``arrays_ms``
+        is the wait for the array reads AFTER rebind (they start before
+        it) plus placement; with array state restored in parallel, also
+        ``read_ms`` (first shard read's start to last one's end) and
+        ``place_ms`` (dispatching every leaf's placement; the copies land
+        later).  Per-rank rebind stats land in ``fresh.rebind_stats``.
+        ``parallel=False`` selects the sequential seed-equivalent path (A/B
+        baseline for benchmarks/bench_restart.py)."""
+        from repro.core import restore
+        timings = {}
+        with span("restore.total", into=timings, key="total_ms") as total:
+            with span("restore.manifest", into=timings,
+                      key="manifest_ms") as sp:
+                source = restore.as_source(ckpt)
+                manifest = source.manifest()
+                # one id per restore, so spans on pool threads group by it
+                rid = f"{manifest['step']}@{self.restart_count + 1}"
+                sp.set(restore=rid)
+                total.set(restore=rid)
+            old_ws = manifest["world_size"]
+            ws = new_world_size or old_ws
+            backend = new_backend or self.backend_name
+            with span("restore.lower_half", into=timings,
+                      key="lower_half_ms", restore=rid):
+                fresh = Cluster(ws, backend, translation=self.translation,
+                                ckpt_dir=self.writer.base if self.writer
+                                else None, ckpt_io=self.ckpt_io)
+            fresh.restored_arrays = self._restart_into(
+                fresh, source, manifest, shardings, parallel, rid, timings)
+        fresh.restart_timings = timings
+        fresh.restore_id = rid
+        fresh.events.append(("restarted", manifest["step"], time.time()))
+        return fresh
+
+    def _restart_into(self, fresh, source, manifest, shardings, parallel,
+                      rid, timings):
+        """Rebind ``fresh``'s ranks from the image and restore the array
+        state (returned; ``None`` without ``shardings``)."""
         from repro.core import ckpt_io as ckpt_io_mod
         from repro.core import restore
-        t0 = time.perf_counter()
-        source = restore.as_source(ckpt)
-        manifest = source.manifest()
-        old_ws = manifest["world_size"]
-        ws = new_world_size or old_ws
-        backend = new_backend or self.backend_name
-        timings = {"manifest_ms": round((time.perf_counter() - t0) * 1e3, 3)}
-        t1 = time.perf_counter()
-        fresh = Cluster(ws, backend, translation=self.translation,
-                        ckpt_dir=self.writer.base if self.writer else None,
-                        ckpt_io=self.ckpt_io)
-        timings["lower_half_ms"] = round((time.perf_counter() - t1) * 1e3, 3)
+        old_ws, ws = manifest["world_size"], fresh.world_size
         if self.writer is not None:
             # release the abandoned writer's thread pool (close() drains the
             # in-flight write; the writer stays queryable via latest())
@@ -347,43 +380,42 @@ class Cluster:
                                      or ckpt_io_mod.default_workers(ws)) \
             if want_arrays else None
         rebind_pool = ckpt_io_mod.IOPool(min(ws, 4)) if parallel else None
+        arrays = arrays_job = None
         try:
             # leaf-restore I/O first: reads/decompression start immediately
             # and overlap the rebind DAGs scheduled next
-            arrays_job = None
             if want_arrays:
                 arrays_job = restore.ArrayRestoreJob(
-                    source, manifest, shardings, io_pool)
+                    source, manifest, shardings, io_pool, restore_id=rid)
             # re-bind each new rank from an old rank image (elastic: wrap
             # around) — one dependency-ordered DAG per rank.  The source
             # caches image text; each new rank gets a fresh parse
             # (descriptor meta must never be shared between ranks — rebind
             # mutates it in place)
-            t2 = time.perf_counter()
-            pairs = []
-            # post-rescale manifests carry the (possibly sparse) member
-            # list: only member slots hold real images, so the wrap-around
-            # maps into members, not range(world_size)
-            members = manifest.get("members") or list(range(old_ws))
-            for r in range(ws):
-                snap = source.rank_state(members[r % len(members)])["mana"]
-                m = Mana(backend, fresh.fabric, r, ws,
-                         translation=snap["translation"])
-                pairs.append((m, snap))
-            fresh.rebind_stats = restore.rebind_world(pairs,
-                                                      pool=rebind_pool)
-            for r, (m, _) in enumerate(pairs):
-                fresh.ranks[r].mana = m
-            timings["rebind_ms"] = round(
-                (time.perf_counter() - t2) * 1e3, 3)
-            t3 = time.perf_counter()
-            if arrays_job is not None:
-                fresh.restored_arrays = arrays_job.result()
-            elif shardings is not None:
-                fresh.restored_arrays = restore.load_arrays(
-                    source, shardings, parallel=False)
-            timings["arrays_ms"] = round(
-                (time.perf_counter() - t3) * 1e3, 3)
+            with span("restore.rebind", into=timings, key="rebind_ms",
+                      restore=rid):
+                pairs = []
+                # post-rescale manifests carry the (possibly sparse) member
+                # list: only member slots hold real images, so the
+                # wrap-around maps into members, not range(world_size)
+                members = manifest.get("members") or list(range(old_ws))
+                for r in range(ws):
+                    snap = source.rank_state(members[r % len(members)])["mana"]
+                    m = Mana(fresh.backend_name, fresh.fabric, r, ws,
+                             translation=snap["translation"])
+                    pairs.append((m, snap))
+                fresh.rebind_stats = restore.rebind_world(pairs,
+                                                          pool=rebind_pool)
+                for r, (m, _) in enumerate(pairs):
+                    fresh.ranks[r].mana = m
+            with span("restore.arrays_wait", into=timings, key="arrays_ms",
+                      restore=rid):
+                if arrays_job is not None:
+                    arrays = arrays_job.result()
+                    timings.update(arrays_job.timings)
+                elif shardings is not None:
+                    arrays = restore.load_arrays(source, shardings,
+                                                 parallel=False)
         finally:
             if arrays_job is not None:
                 # idempotent after result(); REQUIRED if rebind raised
@@ -392,10 +424,7 @@ class Cluster:
             for p in (io_pool, rebind_pool):
                 if p is not None:
                     p.close()
-        timings["total_ms"] = round((time.perf_counter() - t0) * 1e3, 3)
-        fresh.restart_timings = timings
-        fresh.events.append(("restarted", manifest["step"], time.time()))
-        return fresh
+        return arrays
 
 
 class CollectiveHandle:

@@ -65,6 +65,7 @@ from dataclasses import dataclass, field
 from repro.core.callspec import TAG_BASES, coll_tag, handle_vid
 from repro.core.drain import drain_peer, drain_rank
 from repro.core.faults import failpoint
+from repro.core.tracing import span
 
 #: rendezvous channel for the join handshake: the joiner has no world
 #: communicator yet, so the comm-vid half of the tag is 0 by convention
@@ -136,7 +137,16 @@ def shrink(cluster, leaving: int, *, tier=None, cursor=None,
     member) and propagates :class:`DrainStallError` when the scoped drain
     blows its deadline — the supervisor treats either as "fall through to
     the restore ladder"."""
-    t0 = time.perf_counter()
+    timings: dict = {}
+    with span("elastic.shrink", into=timings, key="total_ms", rank=leaving):
+        report = _shrink(cluster, leaving, tier, cursor, timeout, timings)
+    report.downtime_ms = timings["total_ms"]
+    cluster.events.append(("rescaled", "shrink", leaving,
+                           tuple(report.members), time.time()))
+    return report
+
+
+def _shrink(cluster, leaving, tier, cursor, timeout, timings):
     failpoint("elastic.shrink", rank=leaving)
     slot = cluster.ranks[leaving]
     graceful = slot.alive and not slot.halted
@@ -146,56 +156,24 @@ def shrink(cluster, leaving: int, *, tier=None, cursor=None,
                            f"member of the world")
     inheritor = _inheritor_of(leaving, members_after)
     report = RescaleReport(kind="shrink", rank=leaving, graceful=graceful,
-                           members=members_after, inheritor=inheritor)
+                           members=members_after, inheritor=inheritor,
+                           timings=timings)
     deadline = time.time() + timeout
 
     # 1. scoped drain of every edge touching the leaver
-    t1 = time.perf_counter()
-    if graceful:
-        drain_rank(cluster.mana(leaving), timeout, deadline=deadline)
-    for s in members_after:
-        drain_peer(cluster.mana(s), leaving, timeout, deadline=deadline)
-    report.timings["drain_ms"] = round((time.perf_counter() - t1) * 1e3, 3)
+    with span("elastic.drain", into=timings, key="drain_ms", rank=leaving):
+        if graceful:
+            drain_rank(cluster.mana(leaving), timeout, deadline=deadline)
+        for s in members_after:
+            drain_peer(cluster.mana(s), leaving, timeout, deadline=deadline)
 
     # 2. handoff: the leaver pushes its departure payload to the inheritor
     #    over the interposed p2p plane (rescale tag, old world vid — both
     #    ends still share it; the re-point happens after)
-    t2 = time.perf_counter()
-    if graceful:
-        lm, im = cluster.mana(leaving), cluster.mana(inheritor)
-        user_pending = [(s, t, p) for s, t, p in lm.pending_messages
-                        if t < _USER_TAG_MAX]
-        # internal chunks the leaver's drain buffered (a collective round
-        # it never entered): the round dies with the old membership — a
-        # typed cancellation record, never a silent drop
-        report.cancelled.extend((s, t) for s, t, _ in lm.pending_messages
-                                if t >= _USER_TAG_MAX)
-        held = {}
-        if tier is not None:
-            with tier._lock:
-                held = {k: c for k, c in tier.stores.get(leaving, {}).items()}
-        payload = {"op": "leave", "rank": leaving,
-                   "pending": user_pending, "cursor": cursor,
-                   "containers": [
-                       {"step": c.step, "rank": c.rank, "index": c.index,
-                        "data": c.data, "state": c.state, "sha": c.sha}
-                       for c in held.values()]}
-        lm.backend.send(inheritor, _rescale_tag(lm), payload)
-        msg = im._recv_any(leaving, _rescale_tag(im))
-        report.redelivered += len(msg["pending"])
-        im.pending_messages.extend(tuple(p) for p in msg["pending"])
-        report.workload_cursor = msg["cursor"]
-        report.handoff_items = len(msg["containers"]) \
-            + len(msg["pending"]) + (1 if cursor is not None else 0)
-        if tier is not None and msg["containers"]:
-            from repro.core.ckpt_tiers import Container
-            with tier._lock:
-                for c in msg["containers"]:
-                    tier.stores.setdefault(inheritor, {})[
-                        (c["step"], c["rank"])] = Container(
-                            c["step"], c["rank"], c["index"], c["data"],
-                            c["state"], c["sha"])
-    report.timings["handoff_ms"] = round((time.perf_counter() - t2) * 1e3, 3)
+    with span("elastic.handoff", into=timings, key="handoff_ms",
+              rank=leaving):
+        if graceful:
+            _handoff(cluster, leaving, inheritor, tier, cursor, report)
 
     # 3. scavenge the leaver's inbox, then retire it: user traffic is
     #    redelivered through the inheritor's buffered receive; internal
@@ -214,18 +192,49 @@ def shrink(cluster, leaving: int, *, tier=None, cursor=None,
                                list(report.cancelled), time.time()))
 
     # 4. re-point COMM_WORLD on the shrunken world
-    t3 = time.perf_counter()
-    cluster.resize(members_after)
-    report.timings["repoint_ms"] = round((time.perf_counter() - t3) * 1e3, 3)
+    with span("elastic.repoint", into=timings, key="repoint_ms"):
+        cluster.resize(members_after)
 
     # 5. re-pair the replica ring
     if tier is not None:
         report.repair = tier.repair(cluster)
-    report.timings["total_ms"] = round((time.perf_counter() - t0) * 1e3, 3)
-    report.downtime_ms = report.timings["total_ms"]
-    cluster.events.append(("rescaled", "shrink", leaving,
-                           tuple(members_after), time.time()))
     return report
+
+
+def _handoff(cluster, leaving, inheritor, tier, cursor, report):
+    lm, im = cluster.mana(leaving), cluster.mana(inheritor)
+    user_pending = [(s, t, p) for s, t, p in lm.pending_messages
+                    if t < _USER_TAG_MAX]
+    # internal chunks the leaver's drain buffered (a collective round
+    # it never entered): the round dies with the old membership — a
+    # typed cancellation record, never a silent drop
+    report.cancelled.extend((s, t) for s, t, _ in lm.pending_messages
+                            if t >= _USER_TAG_MAX)
+    held = {}
+    if tier is not None:
+        with tier._lock:
+            held = {k: c for k, c in tier.stores.get(leaving, {}).items()}
+    payload = {"op": "leave", "rank": leaving,
+               "pending": user_pending, "cursor": cursor,
+               "containers": [
+                   {"step": c.step, "rank": c.rank, "index": c.index,
+                    "data": c.data, "state": c.state, "sha": c.sha}
+                   for c in held.values()]}
+    lm.backend.send(inheritor, _rescale_tag(lm), payload)
+    msg = im._recv_any(leaving, _rescale_tag(im))
+    report.redelivered += len(msg["pending"])
+    im.pending_messages.extend(tuple(p) for p in msg["pending"])
+    report.workload_cursor = msg["cursor"]
+    report.handoff_items = len(msg["containers"]) \
+        + len(msg["pending"]) + (1 if cursor is not None else 0)
+    if tier is not None and msg["containers"]:
+        from repro.core.ckpt_tiers import Container
+        with tier._lock:
+            for c in msg["containers"]:
+                tier.stores.setdefault(inheritor, {})[
+                    (c["step"], c["rank"])] = Container(
+                        c["step"], c["rank"], c["index"], c["data"],
+                        c["state"], c["sha"])
 
 
 # ---------------------------------------------------------------------------
@@ -249,7 +258,16 @@ def join(cluster, *, tier=None, source=None, cursor=None,
     ``elastic.join.ready`` failpoint) fences the joiner and raises
     :class:`JoinTimeoutError`; the running world's membership is
     untouched."""
-    t0 = time.perf_counter()
+    timings: dict = {}
+    with span("elastic.join", into=timings, key="total_ms"):
+        report = _join(cluster, tier, source, cursor, timings)
+    report.downtime_ms = timings["total_ms"]
+    cluster.events.append(("rescaled", "join", report.rank,
+                           tuple(report.members), time.time()))
+    return report
+
+
+def _join(cluster, tier, source, cursor, timings):
     members_before = cluster.survivors()
     if not members_before:
         raise RescaleError("cannot join an empty world")
@@ -257,78 +275,79 @@ def join(cluster, *, tier=None, source=None, cursor=None,
     joiner = cluster.add_rank()
     new_rank = joiner.rank
     report = RescaleReport(kind="join", rank=new_rank, graceful=True,
-                           members=members_before + [new_rank])
+                           members=members_before + [new_rank],
+                           timings=timings)
 
-    t1 = time.perf_counter()
-    try:
-        # announce -> ready gate -> welcome, all on the rendezvous tag
-        joiner.backend.send(sponsor, JOIN_TAG,
-                            {"op": "join", "rank": new_rank})
-        failpoint("elastic.join.ready", rank=new_rank)
-        sm = cluster.mana(sponsor)
-        hello = sm._recv_any(new_rank, JOIN_TAG)
-        if hello.get("op") != "join":
-            raise RescaleError(f"bad join announce: {hello!r}")
-        sm.backend.send(new_rank, JOIN_TAG,
-                        {"op": "welcome", "members": members_before,
-                         "sponsor": sponsor})
-        welcome = joiner._recv_any(sponsor, JOIN_TAG)
-        if welcome.get("op") != "welcome":
-            raise RescaleError(f"bad join welcome: {welcome!r}")
-    except Exception as e:  # noqa: BLE001 — fence, never poison the world
-        cluster.ranks[new_rank].alive = False
-        cluster.fabric.retire(new_rank)
-        cluster.events.append(("join_fenced", new_rank, time.time()))
-        raise JoinTimeoutError(
-            new_rank, f"joining rank {new_rank} fenced: {e}") from e
-    report.timings["handshake_ms"] = round(
-        (time.perf_counter() - t1) * 1e3, 3)
+    with span("elastic.handshake", into=timings, key="handshake_ms",
+              rank=new_rank):
+        try:
+            # announce -> ready gate -> welcome, all on the rendezvous tag
+            joiner.backend.send(sponsor, JOIN_TAG,
+                                {"op": "join", "rank": new_rank})
+            failpoint("elastic.join.ready", rank=new_rank)
+            sm = cluster.mana(sponsor)
+            hello = sm._recv_any(new_rank, JOIN_TAG)
+            if hello.get("op") != "join":
+                raise RescaleError(f"bad join announce: {hello!r}")
+            sm.backend.send(new_rank, JOIN_TAG,
+                            {"op": "welcome", "members": members_before,
+                             "sponsor": sponsor})
+            welcome = joiner._recv_any(sponsor, JOIN_TAG)
+            if welcome.get("op") != "welcome":
+                raise RescaleError(f"bad join welcome: {welcome!r}")
+        except Exception as e:  # noqa: BLE001
+            # fence, never poison the world
+            cluster.ranks[new_rank].alive = False
+            cluster.fabric.retire(new_rank)
+            cluster.events.append(("join_fenced", new_rank, time.time()))
+            raise JoinTimeoutError(
+                new_rank, f"joining rank {new_rank} fenced: {e}") from e
 
     # stream the slice: sponsor pushes the newest image's containers to
     # the joiner over the rendezvous channel, checksum-verified on arrival
-    t2 = time.perf_counter()
-    image = source
-    if image is None and tier is not None:
-        image = tier.image(cluster)
-    if image is not None and getattr(image, "containers", None):
-        from repro.core.ckpt_tiers import Container, container_sha
-        sm = cluster.mana(sponsor)
-        sent = list(image.containers.values())
-        for c in sent:
-            sm.backend.send(new_rank, JOIN_TAG,
-                            {"op": "shard", "step": c.step, "rank": c.rank,
-                             "index": c.index, "data": c.data,
-                             "state": c.state, "sha": c.sha})
-        sm.backend.send(new_rank, JOIN_TAG, {"op": "done", "count": len(sent)})
-        got: dict[tuple, object] = {}
-        verified = True
-        while True:
-            msg = joiner._recv_any(sponsor, JOIN_TAG)
-            if msg.get("op") == "done":
-                break
-            if container_sha(msg["data"]) != msg["sha"]:
-                verified = False
-                continue
-            got[(msg["step"], msg["rank"])] = Container(
-                msg["step"], msg["rank"], msg["index"], msg["data"],
-                msg["state"], msg["sha"])
-        report.handoff_items = len(got)
-        report.slice_verified = verified and len(got) == len(sent)
-        if tier is not None and got:
-            with tier._lock:
-                for key, c in got.items():
-                    tier.stores.setdefault(new_rank, {})[key] = c
-    report.workload_cursor = cursor
-    report.timings["stream_ms"] = round((time.perf_counter() - t2) * 1e3, 3)
+    with span("elastic.stream", into=timings, key="stream_ms",
+              rank=new_rank):
+        image = source
+        if image is None and tier is not None:
+            image = tier.image(cluster)
+        if image is not None and getattr(image, "containers", None):
+            _stream_slice(cluster, sponsor, joiner, image, tier, report)
+        report.workload_cursor = cursor
 
     # membership changes only now — after the verified transfer
-    t3 = time.perf_counter()
-    cluster.resize(members_before + [new_rank])
-    report.timings["repoint_ms"] = round((time.perf_counter() - t3) * 1e3, 3)
+    with span("elastic.repoint", into=timings, key="repoint_ms"):
+        cluster.resize(members_before + [new_rank])
     if tier is not None:
         report.repair = tier.repair(cluster)
-    report.timings["total_ms"] = round((time.perf_counter() - t0) * 1e3, 3)
-    report.downtime_ms = report.timings["total_ms"]
-    cluster.events.append(("rescaled", "join", new_rank,
-                           tuple(report.members), time.time()))
     return report
+
+
+def _stream_slice(cluster, sponsor, joiner, image, tier, report):
+    from repro.core.ckpt_tiers import Container, container_sha
+    new_rank = joiner.rank
+    sm = cluster.mana(sponsor)
+    sent = list(image.containers.values())
+    for c in sent:
+        sm.backend.send(new_rank, JOIN_TAG,
+                        {"op": "shard", "step": c.step, "rank": c.rank,
+                         "index": c.index, "data": c.data,
+                         "state": c.state, "sha": c.sha})
+    sm.backend.send(new_rank, JOIN_TAG, {"op": "done", "count": len(sent)})
+    got: dict[tuple, object] = {}
+    verified = True
+    while True:
+        msg = joiner._recv_any(sponsor, JOIN_TAG)
+        if msg.get("op") == "done":
+            break
+        if container_sha(msg["data"]) != msg["sha"]:
+            verified = False
+            continue
+        got[(msg["step"], msg["rank"])] = Container(
+            msg["step"], msg["rank"], msg["index"], msg["data"],
+            msg["state"], msg["sha"])
+    report.handoff_items = len(got)
+    report.slice_verified = verified and len(got) == len(sent)
+    if tier is not None and got:
+        with tier._lock:
+            for key, c in got.items():
+                tier.stores.setdefault(new_rank, {})[key] = c

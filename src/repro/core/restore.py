@@ -42,8 +42,8 @@ time.  Rank images wrap around (new rank r restores image r mod old_world).
 from __future__ import annotations
 
 import json
+import math
 import threading
-import time
 from dataclasses import dataclass, field
 from pathlib import Path
 
@@ -54,6 +54,7 @@ from repro.core import ckpt_io
 from repro.core.backends import BACKENDS, backend_family
 from repro.core.faults import failpoint
 from repro.core.descriptors import Kind, Strategy
+from repro.core.tracing import span
 from repro.core.vid import VidTable
 
 _REBIND_TIMEOUT = 60.0
@@ -531,10 +532,20 @@ class ArrayRestoreJob:
     (entries of one file decode concurrently over a shared pread
     descriptor).  The file reads and GIL-releasing decompression overlap
     descriptor rebinding scheduled on the same pool; ``result()`` waits for
-    the reads and performs the elastic reshape placement."""
+    the reads and performs the elastic reshape placement.
 
-    def __init__(self, source, manifest: dict, shardings, pool):
+    Each entry's read is a ``restore.read`` span and the placement a
+    ``restore.place`` span, tagged with ``restore_id``; after ``result()``,
+    ``timings`` holds ``read_ms`` (first read's start to last read's end)
+    and ``place_ms``: the dispatch of every leaf's placement, whose
+    host-to-device copies land after it, behind later host work."""
+
+    def __init__(self, source, manifest: dict, shardings, pool, *,
+                 restore_id: str | None = None):
         self.source = as_source(source)
+        self.restore_id = restore_id
+        self.timings: dict = {}
+        self._read_extent = [float("inf"), float("-inf")]
         self.manifest = manifest
         self._meta = manifest["leaves"]
         flat_sh, self._treedef = jax.tree.flatten(
@@ -576,14 +587,23 @@ class ArrayRestoreJob:
         return arr
 
     def _read_entry(self, step, rank, li, sh) -> None:
-        r = self._reader(step, rank)
-        if _full_cover(sh, self._meta[li]["shape"]):
-            # a full-cover shard is by construction the leaf's ONLY shard
-            self._leaves[li] = r.read(sh["key"])
-        else:
-            # disjoint destination slices: concurrent writers never overlap
-            idx = tuple(slice(a, b) for a, b in sh["index"])
-            self._dest(li)[idx] = r.read(sh["key"])
+        meta = self._meta[li]
+        nbytes = ckpt_io.resolve_dtype(meta["dtype"]).itemsize * math.prod(
+            b - a for a, b in sh["index"])
+        with span("restore.read", restore=self.restore_id, leaf=li,
+                  bytes=nbytes) as sp:
+            r = self._reader(step, rank)
+            if _full_cover(sh, meta["shape"]):
+                # a full-cover shard is by construction the leaf's ONLY shard
+                self._leaves[li] = r.read(sh["key"])
+            else:
+                # disjoint destination slices: concurrent writers never
+                # overlap
+                idx = tuple(slice(a, b) for a, b in sh["index"])
+                self._dest(li)[idx] = r.read(sh["key"])
+        with self._alloc_lock:
+            ext = self._read_extent
+            ext[0], ext[1] = min(ext[0], sp.t0), max(ext[1], sp.t1)
 
     def result(self, timeout: float = 300.0):
         first_err = None
@@ -596,8 +616,14 @@ class ArrayRestoreJob:
         self.close()
         if first_err is not None:
             raise first_err
-        out = [place_leaf(arr, sh)
-               for arr, sh in zip(self._leaves, self._flat_sh)]
+        if self._futures:
+            lo, hi = self._read_extent
+            self.timings["read_ms"] = round((hi - lo) * 1e3, 3)
+        with span("restore.place", into=self.timings, key="place_ms",
+                  restore=self.restore_id,
+                  bytes=sum(a.nbytes for a in self._leaves)):
+            out = [place_leaf(arr, sh)
+                   for arr, sh in zip(self._leaves, self._flat_sh)]
         return jax.tree.unflatten(self._treedef, out)
 
     def close(self) -> None:

@@ -26,6 +26,7 @@ from repro.configs import CkptIOConfig, get_config, smoke_config
 from repro.core import Cluster
 from repro.core import runtime_state as RS
 from repro.core.restore import as_source, translation_plan
+from repro.core.tracing import span
 from repro.data import DataPipeline
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
@@ -120,11 +121,19 @@ class Trainer:
         live rank enters ``allreduce`` over COMM_WORLD through the
         generated interposition layer, so a dead lower half or a dangling
         session token surfaces HERE (fail-fast, classified by the
-        supervisor) rather than only at the next checkpoint."""
-        batch = self._device_batch(self.pipeline.next())
-        self.params, self.opt_state, metrics = self.train_step(
-            self.params, self.opt_state, batch, jnp.int32(self.step))
-        self.rng_key = jax.random.fold_in(self.rng_key, self.step)
+        supervisor) rather than only at the next checkpoint.
+
+        Spans: ``train.feed`` (next batch onto the devices),
+        ``train.dispatch`` (the jitted call returning; a recompile lands
+        here), and in the collective's rank threads ``train.loss_to_host``
+        and ``mpi.allreduce``."""
+        step = self.step
+        with span("train.feed", step=step):
+            batch = self._device_batch(self.pipeline.next())
+        with span("train.dispatch", step=step):
+            self.params, self.opt_state, metrics = self.train_step(
+                self.params, self.opt_state, batch, jnp.int32(step))
+        self.rng_key = jax.random.fold_in(self.rng_key, step)
         self.step += 1
         handle, world = None, 1
         if self.metrics_allreduce:
@@ -138,7 +147,7 @@ class Trainer:
             # handle is waited within the same step — world_loss semantics
             # are unchanged (see docs/performance.md).
             handle = ST.host_allreduce_async(
-                self.cluster, lambda r: float(metrics["loss"]))
+                self.cluster, lambda r: float(metrics["loss"]), step=step)
         for r in range(len(self.cluster.ranks)):
             self.cluster.heartbeat(r)
         if handle is not None:
@@ -215,36 +224,41 @@ class Trainer:
         protocol): array-leaf reads overlap descriptor re-binding on one
         pool (``Cluster.restart``), and the phase timings land in
         ``self.restart_timings`` (mirroring ``checkpoint``'s
-        ``req.timings``)."""
-        src = as_source(ckpt)
-        manifest = src.manifest()
-        rs = src.rank_state(0)
-        rt_meta = rs.get("runtime")
-        self.pipeline.stop()
-        shardings = {"params": self.param_sh, "opt": self.opt_sh}
-        if rt_meta is not None:
-            rt_sh = self.runtime.shardings(rt_meta)
-            if rt_sh:
-                shardings["runtime"] = rt_sh
-        self.cluster = self.cluster.restart(src,
-                                            new_world_size=new_world_size,
-                                            new_backend=new_backend,
-                                            shardings=shardings)
-        arrays = self.cluster.restored_arrays
-        self.restart_timings = self.cluster.restart_timings
-        self.params, self.opt_state = arrays["params"], arrays["opt"]
-        self.step = rs["train_step"]
-        if rt_meta is not None:
-            plan = translation_plan(
-                manifest.get("backend", self.cluster.backend_name),
-                self.cluster.backend_name, self.cluster.mana(0).backend)
-            self.last_runtime_restore = self.runtime.restore(
-                arrays.get("runtime", {}), rt_meta, plan=plan)
-            RS.warn_skipped(self.last_runtime_restore, "train")
-        else:
-            # legacy (pre-runtime-section) checkpoint
-            self.pipeline = DataPipeline.resume(self.cfg, rs["pipeline"],
-                                                mana=self.cluster.mana(0))
+        ``req.timings``).  Spans: ``train.restore`` around the whole, with
+        the restart's ``restore.*`` and ``restore.runtime`` inside."""
+        with span("train.restore") as whole:
+            src = as_source(ckpt)
+            manifest = src.manifest()
+            rs = src.rank_state(0)
+            rt_meta = rs.get("runtime")
+            self.pipeline.stop()
+            shardings = {"params": self.param_sh, "opt": self.opt_sh}
+            if rt_meta is not None:
+                rt_sh = self.runtime.shardings(rt_meta)
+                if rt_sh:
+                    shardings["runtime"] = rt_sh
+            self.cluster = self.cluster.restart(
+                src, new_world_size=new_world_size, new_backend=new_backend,
+                shardings=shardings)
+            rid = self.cluster.restore_id
+            whole.set(restore=rid)
+            arrays = self.cluster.restored_arrays
+            self.restart_timings = self.cluster.restart_timings
+            self.params, self.opt_state = arrays["params"], arrays["opt"]
+            self.step = rs["train_step"]
+            with span("restore.runtime", restore=rid):
+                if rt_meta is not None:
+                    plan = translation_plan(
+                        manifest.get("backend", self.cluster.backend_name),
+                        self.cluster.backend_name,
+                        self.cluster.mana(0).backend)
+                    self.last_runtime_restore = self.runtime.restore(
+                        arrays.get("runtime", {}), rt_meta, plan=plan)
+                    RS.warn_skipped(self.last_runtime_restore, "train")
+                else:
+                    # legacy (pre-runtime-section) checkpoint
+                    self.pipeline = DataPipeline.resume(
+                        self.cfg, rs["pipeline"], mana=self.cluster.mana(0))
         return manifest
 
     # -- live rescale (zero-downtime elasticity) -----------------------

@@ -27,6 +27,7 @@ def cdtype(cfg):
 # norms / rope / conv
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("norm")
 def rmsnorm(x, w, eps=1e-5):
     xf = x.astype(jnp.float32)
     y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
@@ -288,6 +289,7 @@ def attn_specs(cfg):
     return sp
 
 
+@jax.named_scope("attention")
 def attn_apply(ctx, cfg, p, x, *, mode, window=None, cache=None, pos=None,
                use_ring=False):
     """x: [B,S,d] (train/prefill) or [B,d] (decode).
@@ -380,6 +382,7 @@ def mla_specs(cfg):
     }
 
 
+@jax.named_scope("attention")
 def mla_apply(ctx, cfg, p, x, *, mode, cache=None, pos=None):
     m = cfg.mla
     H = cfg.n_heads
@@ -447,6 +450,7 @@ def mlp_specs(cfg, d_ff=None):
     }
 
 
+@jax.named_scope("mlp")
 def mlp_apply(ctx, p, x):
     h = jax.nn.silu(x @ p["wg"]) * (x @ p["wi"])
     h = ctx.act(h, "act_batch", None, "act_mlp") if h.ndim == 3 else h
@@ -496,6 +500,7 @@ def _topk_dispatch(gates, k, C):
     return dispatch, combine, first_choice
 
 
+@jax.named_scope("moe")
 def moe_apply(ctx, cfg, p, x, *, mode):
     """GShard-style capacity dispatch over sequence chunks. x: [B,S,d] or [B,d]."""
     mo = cfg.moe

@@ -145,6 +145,7 @@ def model_specs(cfg):
     return sp
 
 
+@jax.named_scope("embed")
 def embed_tokens(ctx, cfg, params, tokens, patch_embeds=None):
     emb = params["embed"]
     if cfg.n_codebooks > 1:
@@ -163,6 +164,7 @@ def embed_tokens(ctx, cfg, params, tokens, patch_embeds=None):
     return ctx.act(h, *axes)
 
 
+@jax.named_scope("head")
 def lm_head(ctx, cfg, params, h):
     """h: [..., d] -> logits [..., n_codebooks * padded_vocab] (f32)."""
     logits = jnp.einsum("...d,dv->...v", h, params["head"]).astype(jnp.float32)

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.core.tracing import span
 from repro.models import Model
 from repro.models.transformer import VISION_DIM
 from repro.models.params import ParamSpec, is_spec
@@ -22,6 +23,7 @@ NEG_INF = -1e30
 # loss
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("loss")
 def lm_loss(cfg, logits, targets):
     """logits: [B,S,K*Vp] float32; targets: [B,S] or [B,K,S] int32.
     Padded-vocab logits are masked out of the logsumexp."""
@@ -53,7 +55,9 @@ def make_train_step(model: Model, ctx, optimizer):
             return loss + aux, (loss, aux)
 
         grads, (total, (loss, aux)) = _grad_with_aux(loss_fn, params)
-        new_params, new_opt = optimizer.update(grads, opt_state, params, step)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = optimizer.update(grads, opt_state, params,
+                                                   step)
         metrics = {"loss": loss, "aux_loss": aux, "total_loss": total,
                    "grad_norm": global_norm(grads), "step": step + 1}
         return new_params, new_opt, metrics
@@ -82,7 +86,8 @@ class AllreduceHandle:
 
 
 def host_allreduce_async(cluster, value, op: str = "MPI_SUM", *,
-                         timeout: float = 30.0) -> AllreduceHandle:
+                         timeout: float = 30.0,
+                         step: Optional[int] = None) -> AllreduceHandle:
     """Async-start/late-wait split of :func:`host_allreduce`: the rank
     threads enter the collective NOW, the caller keeps dispatching device
     work, and ``handle.wait()`` lands when the result is needed.
@@ -94,10 +99,19 @@ def host_allreduce_async(cluster, value, op: str = "MPI_SUM", *,
     (and the device) keep going, so collective latency hides behind
     backward/optimizer compute instead of adding to it.  Exactly one
     allreduce may be in flight per cluster; wait before starting the next
-    collective (see docs/performance.md, "Async allreduce overlap")."""
+    collective (see docs/performance.md, "Async allreduce overlap").
+
+    In each rank thread, ``train.loss_to_host`` spans the value callable
+    and ``mpi.allreduce`` the collective once the value is on the host,
+    both tagged with ``rank`` and ``step``."""
     def one(m):
-        v = value(m.rank) if callable(value) else value
-        return m.allreduce(m.comm_world(), v, m.op_handles[op])
+        if callable(value):
+            with span("train.loss_to_host", rank=m.rank, step=step):
+                v = value(m.rank)
+        else:
+            v = value
+        with span("mpi.allreduce", rank=m.rank, step=step):
+            return m.allreduce(m.comm_world(), v, m.op_handles[op])
     return AllreduceHandle(cluster.run_collective_async(one, timeout=timeout))
 
 
