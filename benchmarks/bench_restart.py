@@ -5,9 +5,9 @@ Two cells, mirroring bench_ckpt's write-path before/after:
 
   * **parallel restore A/B** — identical v2 checkpoint restored through the
     sequential loader (``load_arrays(parallel=False)``: same format, same
-    group plan, zero threads) vs the entry-fanned parallel engine
-    (``ArrayRestoreJob``: shared-pread readers, GIL-releasing decompress on
-    the pool).  Alternating trials, median of each, speedup gated in
+    group plan, zero threads) vs the parallel engine (``ArrayRestoreJob``:
+    chunk-range tasks over shared positioned-read readers, decoded into
+    the leaves on a pool sized by the host's CPUs).  Alternating trials, median of each, speedup gated in
     ``--smoke``;
   * **backend-pair restart matrix** — checkpoint under EVERY flavor,
     restart under every flavor (all ordered pairs incl. self), asserting

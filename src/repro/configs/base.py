@@ -172,14 +172,17 @@ class CkptIOConfig:
 
     Conservative defaults (lossless, non-incremental) keep raw Cluster
     behavior bit-stable; the training driver opts into zlib + incremental
-    via CLI flags.  ``io_workers=0`` -> min(world_size, cpu).
+    via CLI flags.  ``io_workers`` sizes the writer's pool and the
+    restore read pool; 0 gives the writer min(world_size, cpu) and the
+    reads one worker per usable CPU, at most 16 and at most one per read
+    task (``ckpt_io.read_workers``).
 
     ``pipeline`` selects the pipelined double-buffered snapshot engine
     (lossless — identical bytes on disk); ``pipeline=False`` is the
     snapshot-all-then-write PR 1 path, kept for A/B measurement."""
     codec: str = "none"               # none | zlib | lz4 | int8 (lossy)
     incremental: bool = False         # delta checkpoints (full every keep-th)
-    io_workers: int = 0               # writer/reader pool size (0 = auto)
+    io_workers: int = 0               # writer/read pool size (0 = auto)
     keep: int = 3                     # completed checkpoints retained by GC
     chunk_bytes: int = 4 << 20        # raw bytes per streamed chunk
     pipeline: bool = True             # pipelined double-buffered snapshot

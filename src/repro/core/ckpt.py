@@ -142,7 +142,7 @@ class CheckpointWriter:
       codec             — "none" | "zlib" | "lz4" | "int8" (lossy, opt-in)
       incremental       — write only shards whose content digest changed,
                           with a full checkpoint every ``keep``-th
-      io_workers        — writer/reader pool size; 0 -> min(world_size, cpu)
+      io_workers        — writer pool size; 0 -> min(world_size, cpu)
       chunk_bytes       — raw bytes per streamed chunk
       pipeline          — pipelined snapshot (False -> snapshot-all-then-
                           write, the PR 1 path, kept for A/B)

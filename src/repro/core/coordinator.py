@@ -327,9 +327,13 @@ class Cluster:
         lower_half_ms, rebind_ms, arrays_ms, total_ms}, where ``arrays_ms``
         is the wait for the array reads AFTER rebind (they start before
         it) plus placement; with array state restored in parallel, also
-        ``read_ms`` (first shard read's start to last one's end) and
+        ``read_ms`` (first shard read's start to last one's end),
         ``place_ms`` (dispatching every leaf's placement; the copies land
-        later).  Per-rank rebind stats land in ``fresh.rebind_stats``.
+        later), ``read_workers`` (the read pool's size, by default the
+        host's usable CPUs: ``ckpt_io.read_workers``) and
+        ``read_direct_share`` (bytes of stored-raw chunks read into their
+        place in the leaves, over all bytes read), the last two also args of the ``restore.total`` span.
+        Per-rank rebind stats land in ``fresh.rebind_stats``.
         ``parallel=False`` selects the sequential seed-equivalent path (A/B
         baseline for benchmarks/bench_restart.py)."""
         from repro.core import restore
@@ -353,6 +357,9 @@ class Cluster:
                                 else None, ckpt_io=self.ckpt_io)
             fresh.restored_arrays = self._restart_into(
                 fresh, source, manifest, shardings, parallel, rid, timings)
+            total.set(**{k: timings[k] for k in ("read_workers",
+                                                 "read_direct_share")
+                         if k in timings})
         fresh.restart_timings = timings
         fresh.restore_id = rid
         fresh.events.append(("restarted", manifest["step"], time.time()))
@@ -370,15 +377,13 @@ class Cluster:
             # in-flight write; the writer stays queryable via latest())
             self.writer.close()
         fresh.restart_count = self.restart_count + 1
-        # two pools: leaf reads can queue arbitrarily deep on the I/O pool,
-        # so rebind DAGs get dedicated workers — otherwise FIFO order would
+        # two pools: leaf reads can queue arbitrarily deep on the array
+        # job's own pool (sized by the host's CPUs, not the world size), so
+        # rebind DAGs get dedicated workers — otherwise FIFO order would
         # park every rebind node behind the whole read backlog and a large
         # checkpoint would look like a stalled rebind
         want_arrays = (shardings is not None and parallel
                        and manifest.get("format", 1) >= 2)
-        io_pool = ckpt_io_mod.IOPool(self.ckpt_io.io_workers
-                                     or ckpt_io_mod.default_workers(ws)) \
-            if want_arrays else None
         rebind_pool = ckpt_io_mod.IOPool(min(ws, 4)) if parallel else None
         arrays = arrays_job = None
         try:
@@ -386,7 +391,8 @@ class Cluster:
             # and overlap the rebind DAGs scheduled next
             if want_arrays:
                 arrays_job = restore.ArrayRestoreJob(
-                    source, manifest, shardings, io_pool, restore_id=rid)
+                    source, manifest, shardings,
+                    workers=self.ckpt_io.io_workers, restore_id=rid)
             # re-bind each new rank from an old rank image (elastic: wrap
             # around) — one dependency-ordered DAG per rank.  The source
             # caches image text; each new rank gets a fresh parse
@@ -421,9 +427,8 @@ class Cluster:
                 # idempotent after result(); REQUIRED if rebind raised
                 # before result() ran, else the pread fds leak
                 arrays_job.close()
-            for p in (io_pool, rebind_pool):
-                if p is not None:
-                    p.close()
+            if rebind_pool is not None:
+                rebind_pool.close()
         return arrays
 
 

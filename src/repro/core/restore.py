@@ -524,28 +524,40 @@ def _full_cover(sh: dict, shape: list) -> bool:
 
 
 class ArrayRestoreJob:
-    """Leaf restore in flight on a shared pool.
+    """Leaf restore in flight on a pool of reader threads.
 
-    Constructing the job preallocates every leaf and immediately submits
-    one task PER SHARD ENTRY — not per file — so a checkpoint whose bytes
-    all live in one rank's container still fans out across every worker
-    (entries of one file decode concurrently over a shared pread
-    descriptor).  The file reads and GIL-releasing decompression overlap
-    descriptor rebinding scheduled on the same pool; ``result()`` waits for
-    the reads and performs the elastic reshape placement.
+    Constructing the job opens each container's reader, preallocates the
+    leaves and submits the read tasks at once.  A task covers one chunk
+    range of one shard entry: an entry larger than
+    ``ckpt_io.READ_SPAN_BYTES`` splits into ranges of whole chunks, so one
+    large leaf's bytes are read by several workers, and entries of one file
+    are read concurrently over a shared positioned-read descriptor.  Where
+    an entry's bytes need no array untransform or dtype conversion and its
+    slice of the leaf is one C-contiguous byte range (a full-cover shard, a
+    shard split along the leading axis), each task decodes its chunks
+    into the leaf (``read_into``: a stored-raw chunk is one positioned read
+    through the thread's reused staging buffer, then one copy into place).  Other entries are decoded whole and copied into the
+    leaf's slice, or become the leaf when they cover it.  The reads overlap
+    descriptor rebinding scheduled on another pool; ``result()`` waits for
+    them and performs the elastic reshape placement.
 
-    Each entry's read is a ``restore.read`` span and the placement a
-    ``restore.place`` span, tagged with ``restore_id``; after ``result()``,
-    ``timings`` holds ``read_ms`` (first read's start to last read's end)
-    and ``place_ms``: the dispatch of every leaf's placement, whose
+    ``pool`` is shared and left open; without one the job makes and closes
+    its own, of ``workers`` threads (0: ``ckpt_io.read_workers``, sized by
+    the host's CPUs and the task count).  Each task's read is a
+    ``restore.read`` span (``restore``, ``leaf``, ``part``, ``bytes``) and
+    the placement a ``restore.place`` span, tagged with ``restore_id``.
+    ``timings`` holds ``read_workers`` (the pool's size) and, after
+    ``result()``, ``read_ms`` (first read's start to last read's end),
+    ``read_direct_share`` (bytes of stored-raw chunks read into their place
+    in the leaves, over all bytes read) and ``place_ms``: the dispatch of every leaf's placement, whose
     host-to-device copies land after it, behind later host work."""
 
-    def __init__(self, source, manifest: dict, shardings, pool, *,
-                 restore_id: str | None = None):
+    def __init__(self, source, manifest: dict, shardings, pool=None, *,
+                 workers: int = 0, restore_id: str | None = None):
         self.source = as_source(source)
         self.restore_id = restore_id
-        self.timings: dict = {}
         self._read_extent = [float("inf"), float("-inf")]
+        self._direct = self._total = 0
         self.manifest = manifest
         self._meta = manifest["leaves"]
         flat_sh, self._treedef = jax.tree.flatten(
@@ -554,56 +566,81 @@ class ArrayRestoreJob:
             raise ValueError(f"checkpoint has {len(self._meta)} leaves, "
                              f"target tree has {len(flat_sh)}")
         self._flat_sh = flat_sh
-        # leaves allocate lazily: a full-cover shard's decoded bytes BECOME
-        # the leaf (zero staging copy); only partially-sharded leaves get a
-        # preallocated destination buffer
+        # a full-cover entry that must be decoded whole BECOMES its leaf;
+        # every other leaf is preallocated here as the reads' destination
         self._leaves: list = [None] * len(self._meta)
         self._readers: dict[tuple, object] = {}
-        self._rlock = threading.Lock()
-        self._alloc_lock = threading.Lock()
-        self._futures = [
-            pool.submit(self._read_entry, step, rank, li, sh)
-            for (step, rank), shards in plan_leaf_reads(manifest).items()
-            for li, sh in shards]
+        self._lock = threading.Lock()
+        self._pool = None
+        try:
+            tasks = [task for (step, rank), shards
+                     in plan_leaf_reads(manifest).items()
+                     for li, sh in shards
+                     for task in self._plan(self._reader(step, rank), li, sh)]
+        except BaseException:
+            self.close()
+            raise
+        if pool is None:
+            pool = self._pool = ckpt_io.IOPool(
+                workers or ckpt_io.read_workers(len(tasks)))
+        self.timings: dict = {"read_workers": pool.workers}
+        self._futures = [pool.submit(*task) for task in tasks]
 
     def _reader(self, step, rank):
         key = (step, rank)
-        with self._rlock:
-            r = self._readers.get(key)
-            if r is None:
-                r = self._readers[key] = self.source.reader(step, rank)
-            return r
+        r = self._readers.get(key)
+        if r is None:
+            r = self._readers[key] = self.source.reader(step, rank)
+        return r
 
-    def _dest(self, li: int) -> np.ndarray:
-        arr = self._leaves[li]
-        if arr is None:
-            with self._alloc_lock:
-                arr = self._leaves[li]
-                if arr is None:
-                    meta = self._meta[li]
-                    arr = self._leaves[li] = np.empty(
-                        meta["shape"],
-                        dtype=ckpt_io.resolve_dtype(meta["dtype"]))
-        return arr
-
-    def _read_entry(self, step, rank, li, sh) -> None:
+    def _plan(self, r, li: int, sh: dict) -> list:
+        """The read tasks, ``(fn, *args)``, of one shard entry."""
         meta = self._meta[li]
+        entry = r.entry(sh["key"])
+        idx = tuple(slice(a, b) for a, b in sh["index"])
         nbytes = ckpt_io.resolve_dtype(meta["dtype"]).itemsize * math.prod(
             b - a for a, b in sh["index"])
+        same_bytes = (entry.get("qmeta") is None
+                      and entry["enc_dtype"] == entry["dtype"]
+                      == meta["dtype"])
+        if not same_bytes and _full_cover(sh, meta["shape"]):
+            # a full-cover shard is by construction the leaf's ONLY shard
+            return [(self._read_copy, r, sh["key"], li, None, nbytes)]
+        if self._leaves[li] is None:
+            self._leaves[li] = np.empty(
+                meta["shape"], dtype=ckpt_io.resolve_dtype(meta["dtype"]))
+        view = self._leaves[li][idx + (...,)]
+        if not (same_bytes and view.flags.c_contiguous
+                and nbytes == entry["nbytes"]):
+            return [(self._read_copy, r, sh["key"], li, idx, nbytes)]
+        out = view.reshape(-1).view(np.uint8)
+        return [(self._read_into, r, sh["key"], li, part, out[lo:hi],
+                 first, last)
+                for part, (first, last, lo, hi) in enumerate(
+                    ckpt_io.chunk_spans(entry, ckpt_io.READ_SPAN_BYTES))]
+
+    def _read_into(self, r, key, li, part, out, first, last) -> None:
+        # disjoint byte ranges of the leaf: concurrent writers never overlap
         with span("restore.read", restore=self.restore_id, leaf=li,
-                  bytes=nbytes) as sp:
-            r = self._reader(step, rank)
-            if _full_cover(sh, meta["shape"]):
-                # a full-cover shard is by construction the leaf's ONLY shard
-                self._leaves[li] = r.read(sh["key"])
+                  part=part, bytes=out.nbytes) as sp:
+            direct = r.read_into(key, out, first, last)
+        self._done(sp, direct, out.nbytes)
+
+    def _read_copy(self, r, key, li, idx, nbytes) -> None:
+        with span("restore.read", restore=self.restore_id, leaf=li,
+                  part=0, bytes=nbytes) as sp:
+            if idx is None:
+                self._leaves[li] = r.read(key)
             else:
-                # disjoint destination slices: concurrent writers never
-                # overlap
-                idx = tuple(slice(a, b) for a, b in sh["index"])
-                self._dest(li)[idx] = r.read(sh["key"])
-        with self._alloc_lock:
+                self._leaves[li][idx] = r.read(key)
+        self._done(sp, 0, nbytes)
+
+    def _done(self, sp, direct: int, nbytes: int) -> None:
+        with self._lock:
             ext = self._read_extent
             ext[0], ext[1] = min(ext[0], sp.t0), max(ext[1], sp.t1)
+            self._direct += direct
+            self._total += nbytes
 
     def result(self, timeout: float = 300.0):
         first_err = None
@@ -619,6 +656,8 @@ class ArrayRestoreJob:
         if self._futures:
             lo, hi = self._read_extent
             self.timings["read_ms"] = round((hi - lo) * 1e3, 3)
+            self.timings["read_direct_share"] = round(
+                self._direct / max(self._total, 1), 4)
         with span("restore.place", into=self.timings, key="place_ms",
                   restore=self.restore_id,
                   bytes=sum(a.nbytes for a in self._leaves)):
@@ -627,12 +666,15 @@ class ArrayRestoreJob:
         return jax.tree.unflatten(self._treedef, out)
 
     def close(self) -> None:
-        """Release the shared readers (idempotent; ``result()`` calls it).
-        Callers that abandon the job after a failure elsewhere in the
-        restart MUST close it, or the pread fds leak."""
-        with self._rlock:
+        """Release the readers and the job's own pool (idempotent;
+        ``result()`` calls it).  Callers that abandon the job after a
+        failure elsewhere in the restart MUST close it, or the pread fds
+        leak."""
+        with self._lock:
             for r in self._readers.values():
                 r.close()
+        if self._pool is not None:
+            self._pool.close()
 
 
 def place_leaf(arr: np.ndarray, sharding):
@@ -684,25 +726,17 @@ def load_arrays(ckpt, shardings, *, io_workers=None, parallel=True,
 
     ``ckpt`` is a committed step directory OR any checkpoint source (see
     :func:`as_source` — e.g. a RAM-tier ``TierImage``).  ``parallel=True``
-    fans shard-group reads out over ``pool`` (or a transient pool of
-    ``io_workers``); ``parallel=False`` is the sequential baseline.  Handles
+    fans chunk-range reads out over ``pool`` (or a transient pool of
+    ``io_workers``, by default sized by the host: ``ckpt_io.read_workers``);
+    ``parallel=False`` is the sequential baseline.  Handles
     both the v2 chunked/compressed/incremental format and legacy v1 npz
     images (v1 requires a directory source)."""
     src = as_source(ckpt)
     manifest = src.manifest()
     if manifest.get("format", 1) >= 2:
         if parallel:
-            own = pool is None
-            if own:
-                pool = ckpt_io.IOPool(
-                    io_workers
-                    or ckpt_io.default_workers(manifest["world_size"]))
-            try:
-                return ArrayRestoreJob(src, manifest, shardings,
-                                       pool).result()
-            finally:
-                if own:
-                    pool.close()
+            return ArrayRestoreJob(src, manifest, shardings, pool,
+                                   workers=io_workers or 0).result()
         leaves = _load_leaves_v2_seq(src, manifest)
     else:
         step_dir = getattr(src, "path", None)

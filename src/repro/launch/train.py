@@ -369,7 +369,8 @@ def main():
     ap.add_argument("--no-ckpt-incremental", dest="ckpt_incremental",
                     action="store_false")
     ap.add_argument("--ckpt-io-workers", type=int, default=0,
-                    help="writer/reader pool size (0 = min(world, cpu))")
+                    help="writer/read pool size (0 = writer min(world, "
+                         "cpu), reads one per usable cpu up to 16)")
     ap.add_argument("--ckpt-keep", type=int, default=3)
     ap.add_argument("--ckpt-pipeline", action="store_true", default=True,
                     help="pipelined double-buffered snapshot (default)")

@@ -320,6 +320,179 @@ def test_restore_parallel_workers_match_serial(tmp_path):
     w.close()
 
 
+def _hand_image(base, codec_name, layout):
+    """A committed 2-rank v2 image written by hand, 256-byte chunks: each
+    2-D leaf is split over the two ranks along axis 0 or 1 (``layout``
+    "axis0" / "axis1") or held whole by rank 0 ("full"); the scalar is
+    always whole on rank 0.  Returns (step dir, manifest, original
+    leaves)."""
+    rng = np.random.default_rng(7)
+    leaves = [rng.normal(size=(16, 96)).astype(np.float32),   # noise: raw
+              np.zeros((16, 96), np.float32),      # compresses under zlib
+              rng.normal(size=(8, 40)).astype(jnp.bfloat16),
+              np.arange(16 * 12, dtype=np.int32).reshape(16, 12),
+              np.array(2.5, np.float32)]
+    step_dir = base / "step_00000001"
+    per_rank, meta = {0: {}, 1: {}}, []
+    for li, arr in enumerate(leaves):
+        shape = list(arr.shape)
+        axis = {"axis0": 0, "axis1": 1}.get(layout) if arr.ndim else None
+        if axis is None:
+            parts = [(0, [[0, s] for s in shape])]
+        else:
+            mid = shape[axis] // 2
+            parts = [(r, [[lo, hi] if a == axis else [0, s]
+                          for a, s in enumerate(shape)])
+                     for r, (lo, hi) in enumerate([(0, mid),
+                                                   (mid, shape[axis])])]
+        shards = []
+        for r, index in parts:
+            key = f"{li}.{r}"
+            per_rank[r][key] = arr[tuple(slice(a, b) for a, b in index)]
+            shards.append({"rank": r, "key": key, "index": index})
+        meta.append({"shape": shape, "dtype": ckpt_io.dtype_name(arr.dtype),
+                     "shards": shards})
+    for r, arrays in per_rank.items():
+        ckpt_io.write_rank_shards(step_dir / f"rank{r:05d}", arrays,
+                                  ckpt_io.get_codec(codec_name),
+                                  chunk_bytes=256)
+        (step_dir / f"rank{r:05d}" / "state.json").write_text("{}")
+    manifest = {"format": 2, "step": 1, "world_size": 2, "mesh": None,
+                "leaves": meta}
+    (step_dir / "manifest.json").write_text(json.dumps(manifest))
+    (step_dir / "COMMIT").write_text("ok")
+    return step_dir, manifest, leaves
+
+
+def _ram_source(step_dir, manifest):
+    """The RAM tier's image of a committed step dir (MemoryShardReader)."""
+    from repro.core.ckpt_tiers import Container, TierImage
+    containers = {}
+    for rdir in sorted(step_dir.glob("rank*")):
+        r = int(rdir.name[len("rank"):])
+        containers[(manifest["step"], r)] = Container(
+            manifest["step"], r, ckpt_io.read_rank_index(rdir),
+            (rdir / ckpt_io.BIN_NAME).read_bytes(), "{}", "")
+    return TierImage(manifest["step"], manifest, containers)
+
+
+@pytest.mark.parametrize("source", ["dir", "ram"])
+@pytest.mark.parametrize("layout", ["full", "axis0", "axis1"])
+@pytest.mark.parametrize("codec_name", ["none", "zlib"])
+def test_array_restore_job_matches_sequential_loader(
+        tmp_path, monkeypatch, codec_name, layout, source):
+    """Chunk-range reads into the leaf (full cover, axis-0 shards) and the
+    decode-then-copy fallback (axis-1 shards: non-contiguous slices) give
+    the sequential loader's bytes, from disk and from the RAM tier."""
+    from repro.core.restore import (ArrayRestoreJob, DirCheckpointSource,
+                                    _load_leaves_v2_seq)
+    monkeypatch.setattr(ckpt_io, "READ_SPAN_BYTES", 1024)   # 4-chunk parts
+    step_dir, manifest, leaves = _hand_image(tmp_path, codec_name, layout)
+    flags = {c[2] for rdir in step_dir.glob("rank*")
+             for e in ckpt_io.read_rank_index(rdir)["entries"].values()
+             for c in e["chunks"]}
+    assert flags == ({0, 1} if codec_name == "zlib" else {1})
+    src = (DirCheckpointSource(step_dir) if source == "dir"
+           else _ram_source(step_dir, manifest))
+    seq = _load_leaves_v2_seq(src, manifest)
+    job = ArrayRestoreJob(src, manifest, [None] * len(leaves), workers=4)
+    out = job.result()
+    for arr, got, ref in zip(leaves, out, seq):
+        got = np.asarray(got)
+        assert got.dtype == ref.dtype == arr.dtype
+        assert got.shape == ref.shape == arr.shape
+        assert got.tobytes() == ref.tobytes() == arr.tobytes()
+    # every byte lands in place unless a chunk is compressed or a slice is
+    # not contiguous
+    share = job.timings["read_direct_share"]
+    assert (share == 1.0) == (codec_name == "none" and layout != "axis1")
+
+
+@pytest.mark.parametrize("source", ["dir", "ram"])
+def test_truncated_container_raises_not_partial_leaf(tmp_path, source):
+    """A torn ``shards.bin`` (the fault harness truncates to 60%) fails the
+    restore with IOError on the straight-into-the-leaf path too."""
+    from repro.core.restore import DirCheckpointSource
+    step_dir, manifest, _ = _hand_image(tmp_path, "none", "full")
+    binf = step_dir / "rank00000" / ckpt_io.BIN_NAME
+    binf.write_bytes(binf.read_bytes()[:int(binf.stat().st_size * 0.6)])
+    src = (DirCheckpointSource(step_dir) if source == "dir"
+           else _ram_source(step_dir, manifest))
+    with pytest.raises(IOError, match="short read"):
+        load_arrays(src, [None] * len(manifest["leaves"]))
+
+
+def test_large_entry_reads_in_parts_under_one_restore_id(tmp_path,
+                                                         monkeypatch):
+    from repro.core import restore
+    monkeypatch.setattr(ckpt_io, "READ_SPAN_BYTES", 64 << 10)
+    seen = []
+    real_span = restore.span
+
+    def recording_span(name, **kw):
+        seen.append((name, kw))
+        return real_span(name, **kw)
+
+    monkeypatch.setattr(restore, "span", recording_span)
+    state = {"big": jnp.asarray(np.random.default_rng(1)
+                                .normal(size=(256, 256)).astype(np.float32)),
+             "small": jnp.ones((3, 5), jnp.bfloat16)}
+    c = Cluster(1, "mpich", ckpt_dir=tmp_path / "ck",
+                ckpt_io=CkptIOConfig(chunk_bytes=16 << 10))
+    c.checkpoint(3, state, None).wait()
+    fresh = c.restart(c.writer.latest(), new_backend="openmpi",
+                      shardings={k: None for k in state})
+    fresh.writer.close()
+    reads = [kw for name, kw in seen if name == "restore.read"]
+    by_leaf = {}
+    for kw in reads:
+        by_leaf.setdefault(kw["leaf"], []).append(kw)
+    big, small = by_leaf[0], by_leaf[1]
+    assert sorted(kw["part"] for kw in big) == [0, 1, 2, 3]
+    assert sum(kw["bytes"] for kw in big) == state["big"].nbytes
+    assert [(kw["part"], kw["bytes"]) for kw in small] == [
+        (0, state["small"].nbytes)]
+    assert {kw["restore"] for kw in reads} == {fresh.restore_id}
+    np.testing.assert_array_equal(np.asarray(fresh.restored_arrays["big"]),
+                                  np.asarray(state["big"]))
+
+
+def test_read_pool_sized_by_host_not_world(tmp_path, monkeypatch):
+    """A world-2 restore on a host with 8 usable CPUs reads with more than
+    2 workers; the checkpoint writer's pool stays min(world_size, cpu)."""
+    monkeypatch.setattr(ckpt_io, "usable_cpus", lambda: 8)
+    monkeypatch.setattr("os.cpu_count", lambda: 8)
+    state = {f"w{i}": jnp.full((64, 64), i, jnp.float32) for i in range(6)}
+    c = Cluster(2, "craympi", ckpt_dir=tmp_path / "ck")
+    c.checkpoint(1, state, None).wait()
+    assert c.writer.io_workers == 2
+    fresh = c.restart(c.writer.latest(), new_backend="exampi",
+                      shardings={k: None for k in state})
+    fresh.writer.close()
+    assert fresh.restart_timings["read_workers"] == 6     # one per task
+    assert fresh.writer.io_workers == 2
+    for k, x in state.items():
+        np.testing.assert_array_equal(np.asarray(fresh.restored_arrays[k]),
+                                      np.asarray(x))
+
+
+@pytest.mark.parametrize("codec_name", ["none", "zlib"])
+def test_read_direct_share_in_restart_timings(tmp_path, codec_name):
+    state = {"noise": jnp.asarray(np.random.default_rng(2)
+                                  .normal(size=(64, 64)).astype(np.float32)),
+             "zeros": jnp.zeros((64, 64), jnp.float32)}
+    c = Cluster(2, "craympi", ckpt_dir=tmp_path / "ck",
+                ckpt_io=CkptIOConfig(codec=codec_name))
+    c.checkpoint(1, state, None).wait()
+    fresh = c.restart(c.writer.latest(), shardings={k: None for k in state})
+    fresh.writer.close()
+    share = fresh.restart_timings["read_direct_share"]
+    if codec_name == "none":
+        assert share == 1.0
+    else:                     # the zeros compress, the noise is stored raw
+        assert share == 0.5
+
+
 def _make_legacy_v1_ckpt(base, arrays):
     """Hand-build a seed-format (v1) checkpoint: monolithic npz per rank,
     manifest without a ``format`` field."""

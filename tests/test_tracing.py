@@ -97,8 +97,12 @@ def test_checkpoint_and_restart_spans_in_the_trace(tmp_path):
     assert len(reads) == len(state)
     assert sum(a["bytes"] for a in reads) == nbytes
     assert all(a["restore"] == rid for a in reads)
+    assert all(a["part"] == 0 for a in reads)      # each under the span
     assert [a["bytes"] for a in by["restore.place"]] == [nbytes]
     assert [a["restore"] for a in by["restore.total"]] == [rid]
+    total = by["restore.total"][0]
+    assert total["read_workers"] == fresh.restart_timings["read_workers"]
+    assert float(total["read_direct_share"]) == 1.0
 
     for k in ("drain_ms", "rank_state_ms", "snapshot_ms", "enqueue_ms",
               "blocking_ms", "persist_ms"):
