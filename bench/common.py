@@ -1,6 +1,6 @@
-"""What every driver of the benchmark shares: the cell's files, seeds,
-host spans, the count of compiles, state fingerprints and the checks that
-decide ``correct``.
+"""What every driver of the benchmark shares: the cell's files, its
+architecture's module, seeds, host spans, the count of compiles, state
+fingerprints and the checks that decide ``correct``.
 
 Nothing here touches a device at import.  The program under test is
 imported from ``<root>/src`` only where a driver needs it.
@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import importlib.util
 import json
 import sys
 import time
@@ -20,8 +21,17 @@ import numpy as np
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
+ARCHS = BENCH / "archs"
 if str(ROOT / "src") not in sys.path:
     sys.path.insert(0, str(ROOT / "src"))
+
+
+def load_file(path, name):
+    """The Python file at ``path`` as a module called ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def load_json(path):
@@ -61,31 +71,21 @@ def derived_seeds(seed):
                                            words)}
 
 
+def arch(config):
+    """The module of the configuration's architecture,
+    ``bench/archs/<model_type>.py``: its program mapping, weights' layout,
+    reference and model FLOPs.  An architecture is therefore a new file."""
+    name = config["model_type"]
+    path = ARCHS / f"{name}.py"
+    if not path.exists():
+        known = sorted(p.stem for p in ARCHS.glob("*.py"))
+        raise KeyError(f"no architecture {name!r} at {path}; known: {known}")
+    return load_file(path, f"bench_archs_{name.replace('.', '_')}")
+
+
 def program_config(config):
-    """The program's ``ModelConfig`` for a configuration file: the preset
-    the file names, with every size the file states, checked against the
-    settings the file states and the program does not take as sizes."""
-    from repro.configs import get_config
-    base = get_config(config["program_arch"])
-    cfg = dataclasses.replace(
-        base, n_layers=config["num_hidden_layers"],
-        d_model=config["hidden_size"], d_ff=config["intermediate_size"],
-        n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"], head_dim=config["head_dim"],
-        vocab_size=config["vocab_size"])
-    want = {"rope_theta": config["rope_theta"], "norm_eps": config["rms_norm_eps"],
-            "tie_embeddings": config["tie_word_embeddings"],
-            "qkv_bias": config["attention_bias"],
-            "param_dtype": config["torch_dtype"],
-            "compute_dtype": config["torch_dtype"],
-            "opt_state_dtype": config["optimizer_state_dtype"],
-            "padded_vocab": config["padded_vocab"]}
-    got = {k: getattr(cfg, k) for k in want}
-    if got != want:
-        bad = {k: (got[k], want[k]) for k in want if got[k] != want[k]}
-        raise ValueError(f"the program's {cfg.name} differs from the "
-                         f"configuration file: {bad}")
-    return cfg
+    """The program's ``ModelConfig`` for a configuration file."""
+    return arch(config).program_config(config)
 
 
 class Recorder:

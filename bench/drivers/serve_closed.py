@@ -23,7 +23,7 @@ import time
 import jax
 import numpy as np
 
-from bench import common, flops, reference, weights
+from bench import common, weights
 
 
 def replay_set(mix, vocab, seed):
@@ -99,12 +99,13 @@ def run(r):
                     break
         window_s = r.t_close - r.t_open
         gaps, n_tokens, model_flops = [], 0, 0.0
+        arch = common.arch(config)
         for s, st in stamps.items():
             S = len(eng.sessions[s].prompt)
             n_tokens += len(st)
             for j, _ in st:
-                model_flops += (flops.prefill_flops(config, S) if j == 0
-                                else flops.decode_flops(config, S + j - 1))
+                model_flops += (arch.prefill_flops(config, S) if j == 0
+                                else arch.decode_flops(config, S + j - 1))
             gaps += [b[1] - a[1] for a, b in zip(st, st[1:])]
         r.values["serve_tokens_per_s"] = n_tokens / window_s
         r.values["itl_p95_ms"] = float(np.percentile(gaps, 95)) * 1e3
@@ -148,8 +149,9 @@ def served_gap(config, seed, picked, length, quant=False):
         return float("inf")
     params = weights.make(config, seed)
     seqs = [p + g[:-1] for p, g, _ in picked]
-    ref = reference.serve_logits(config, params, seqs, length)
-    ctl = (reference.serve_logits(config, params, seqs, length, quant=True)
+    serve_logits = common.arch(config).serve_logits
+    ref = serve_logits(config, params, seqs, length)
+    ctl = (serve_logits(config, params, seqs, length, quant=True)
            if quant else None)
     worst = 0.0
     for i, (p, g, _) in enumerate(picked):

@@ -18,7 +18,7 @@ import time
 
 import numpy as np
 
-from bench import common, flops, training
+from bench import common, training
 
 
 def run(r):
@@ -48,7 +48,7 @@ def run(r):
         tokens = mix["batch"] * mix["seq_len"]
         r.values["train_tokens_per_s"] = steps * tokens / window_s
         r.values["step_s"] = r.rec.durations("step", r.t_open, r.t_close)
-        r.values["step_flops"] = flops.train_step_flops(
+        r.values["step_flops"] = common.arch(r.config).train_step_flops(
             r.config, mix["batch"], mix["seq_len"])
         r.attempted = steps + len(saves)
         for req, _ in saves:

@@ -1,10 +1,11 @@
-"""The plain reference of the configurations the benchmark runs: a dense
-decoder block as the configuration file states it (RMSNorm, rotary
-positions with the file's ``rope_theta``, grouped-query causal softmax
-attention, SwiGLU), written in ``jax.numpy`` in float32 with every matrix
-product at ``Precision.HIGHEST``.  It imports nothing of the program and is
-given only the configuration file, weights made by ``weights.make`` from
-the seed, and the tokens the benchmark sent.
+"""The plain reference's shared part: the loss, three AdamW steps as the
+configuration states them, and the helpers every architecture's forward
+uses.  The forward itself, the block as the configuration file states it,
+is the architecture's ``logits`` and ``serve_logits``
+(``bench/archs/<model_type>.py``), written in ``jax.numpy`` in float32 with
+every matrix product at ``Precision.HIGHEST``.  The reference imports
+nothing of the program and is given only the configuration file, weights
+made by ``weights.make`` from the seed, and the tokens the benchmark sent.
 
 ``quant=True`` is the control: the same reference with every matrix
 product's operands rounded to float8 e4m3 (per-tensor scale, gradient
@@ -13,11 +14,11 @@ configuration states.
 """
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import common
 
 HIGHEST = jax.lax.Precision.HIGHEST
 NEG = -1e30
@@ -40,64 +41,6 @@ def _rmsnorm(x, w, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
 
 
-def _rope(x, theta):
-    """x: [B, S, n, hd]; rotate-half convention over the last axis."""
-    S, hd = x.shape[1], x.shape[-1]
-    half = hd // 2
-    inv = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
-    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * inv[None]
-    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def layer(config, p, x, quant=False):
-    """One decoder layer over x: [B, S, d] float32; p holds this layer's
-    float32 weights {wq, wk, wv, wo, wg, wi, wo2, ln1, ln2}."""
-    B, S, _ = x.shape
-    H, K, hd = (config["num_attention_heads"], config["num_key_value_heads"],
-                config["head_dim"])
-    eps = config["rms_norm_eps"]
-    h = _rmsnorm(x, p["ln1"], eps)
-    q = _mm("bsd,df->bsf", h, p["wq"], quant).reshape(B, S, H, hd)
-    k = _mm("bsd,df->bsf", h, p["wk"], quant).reshape(B, S, K, hd)
-    v = _mm("bsd,df->bsf", h, p["wv"], quant).reshape(B, S, K, hd)
-    q, k = _rope(q, config["rope_theta"]), _rope(k, config["rope_theta"])
-    k, v = jnp.repeat(k, H // K, axis=2), jnp.repeat(v, H // K, axis=2)
-    s = _mm("bqhd,bkhd->bhqk", q, k, quant) / math.sqrt(hd)
-    causal = jnp.tril(jnp.ones((S, S), bool))
-    a = jax.nn.softmax(jnp.where(causal, s, NEG), axis=-1)
-    o = _mm("bhqk,bkhd->bqhd", a, v, quant).reshape(B, S, H * hd)
-    x = x + _mm("bsf,fd->bsd", o, p["wo"], quant)
-    h = _rmsnorm(x, p["ln2"], eps)
-    g = _mm("bsd,df->bsf", h, p["wg"], quant)
-    u = _mm("bsd,df->bsf", h, p["wi"], quant)
-    return x + _mm("bsf,fd->bsd", jax.nn.silu(g) * u, p["wo2"], quant)
-
-
-def layer_weights(params, i):
-    """Layer ``i`` of a ``weights.make`` tree, as float32."""
-    seg = params["segments"][0]
-    f = lambda x: x[i].astype(jnp.float32)  # noqa: E731
-    return {"wq": f(seg["attn"]["wq"]), "wk": f(seg["attn"]["wk"]),
-            "wv": f(seg["attn"]["wv"]), "wo": f(seg["attn"]["wo"]),
-            "wg": f(seg["ffn"]["wg"]), "wi": f(seg["ffn"]["wi"]),
-            "wo2": f(seg["ffn"]["wo"]), "ln1": f(seg["ln1"]),
-            "ln2": f(seg["ln2"])}
-
-
-def logits(config, params, tokens, quant=False):
-    """[B, S] int tokens -> [B, S, padded_vocab] float32 logits."""
-    f32 = jnp.float32
-    x = jnp.take(params["embed"].astype(f32), tokens, axis=0)
-    body = jax.checkpoint(lambda x, i: layer(config, layer_weights(params, i),
-                                              x, quant))
-    for i in range(config["num_hidden_layers"]):
-        x = body(x, i)
-    x = _rmsnorm(x, params["final_norm"].astype(f32), config["rms_norm_eps"])
-    return _mm("bsd,dv->bsv", x, params["head"].astype(f32), quant)
-
-
 def lm_loss(config, lg, targets):
     """Mean next-token cross-entropy over the real (unpadded) vocabulary."""
     V = config["vocab_size"]
@@ -115,6 +58,8 @@ def _loss_and_grad(config, params, batch, quant):
     """Loss and float32 gradient over a batch, one row at a time."""
     f32 = jnp.float32
     p32 = jax.tree.map(lambda x: x.astype(f32), params)
+
+    logits = common.arch(config).logits
 
     def row_loss(p, tok, tgt):
         return lm_loss(config, logits(config, p, tok[None], quant), tgt[None])
@@ -189,27 +134,3 @@ def train_readings(config, opt, params, batches, quant=False):
 def _norms(tree):
     return jnp.stack([jnp.sqrt(jnp.sum(jnp.square(x)))
                       for x in jax.tree.leaves(tree)])
-
-
-# ---------------------------------------------------------------------------
-# serving: logits at every position of the sequences that were served
-# ---------------------------------------------------------------------------
-
-def serve_logits(config, params, seqs, length, quant=False):
-    """Reference logits for each sequence (prompt + served tokens but the
-    last), layer by layer over the batch padded to ``length``.  Returns a
-    list of [len(seq), vocab_size] float32 host arrays."""
-    f32 = jnp.float32
-    toks = np.zeros((len(seqs), length), np.int32)
-    for i, s in enumerate(seqs):
-        toks[i, :len(s)] = s
-    x = jnp.take(params["embed"], jnp.asarray(toks), axis=0).astype(f32)
-    step = jax.jit(lambda p, x: layer(config, p, x, quant))
-    for i in range(config["num_hidden_layers"]):
-        x = step(layer_weights(params, i), x)
-    head = jax.jit(lambda x, w, n: _mm(
-        "sd,dv->sv", _rmsnorm(x, n, config["rms_norm_eps"]), w, quant))
-    w, n = params["head"].astype(f32), params["final_norm"].astype(f32)
-    V = config["vocab_size"]
-    return [np.asarray(head(x[i], w, n))[:len(s), :V]
-            for i, s in enumerate(seqs)]

@@ -4,10 +4,12 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell is an entry of ``BENCHMARK.json``.  Its configuration file and its
-traffic file are found by name; the traffic file names the driver
-(``bench/drivers/<driver>.py``) that runs it, and every per-layer metric is
-read by ``bench/metrics/<metric>.py``.  A new configuration, traffic mix or
-metric is therefore a new file, never an edit.
+traffic file are found by name; the configuration's ``model_type`` names its
+architecture (``bench/archs/<model_type>.py``), the traffic file names the
+driver (``bench/drivers/<driver>.py``) that runs it, and every per-layer
+metric is read by ``bench/metrics/<metric>.py``.  A new architecture,
+configuration, traffic mix or metric is therefore a new file, never an
+edit.
 
 The run sets up (weights from the seed, every shape the window uses
 compiled or loaded from ``<checkout>/.jax_cache``), measures for
@@ -28,7 +30,6 @@ T_START = time.perf_counter()
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
 import glob  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -50,11 +51,7 @@ def load_module(kind, name):
     path = BENCH / kind / f"{name}.py"
     if not path.exists():
         raise FileNotFoundError(f"no {kind[:-1]} {name!r} at {path}")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_{kind}_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return common.load_file(path, f"bench_{kind}_{name.replace('.', '_')}")
 
 
 class Run:

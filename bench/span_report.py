@@ -19,7 +19,7 @@ temporary directory goes:
 - ``scopes`` (training cells): the train step's device time by
   ``jax.named_scope``, each operation counted by its own time (a loop holds
   its body's operations) and named by the innermost scope in its HLO
-  ``op_name``.
+  ``op_name``: one of ``SCOPES`` or of the architecture's own ``SCOPES``.
 
 The report goes to ``--out``; its headline numbers are printed as one line.
 """
@@ -41,7 +41,8 @@ from bench import common, devtrace, training  # noqa: E402
 from bench import run as bench_run  # noqa: E402
 from bench.metrics import _spans  # noqa: E402
 
-#: the device scopes the model and the step name (``jax.named_scope``)
+#: the device scopes every architecture's model and the step name
+#: (``jax.named_scope``); an architecture adds its own
 SCOPES = ("embed", "attention", "mlp", "moe", "norm", "head", "loss",
           "optimizer")
 TOP = 10
@@ -61,10 +62,10 @@ def exclusive_ns(events):
     return own
 
 
-def scope_of(op_name):
-    """The innermost of ``SCOPES`` in an HLO ``op_name``, else "other"."""
+def scope_of(op_name, scopes):
+    """The innermost of ``scopes`` in an HLO ``op_name``, else "other"."""
     for part in reversed(re.split(r"[/()]", op_name or "")):
-        if part in SCOPES:
+        if part in scopes:
             return part
     return "other"
 
@@ -108,11 +109,11 @@ def device_lines(path, window, module):
     return [], []
 
 
-def scope_table(ops, names):
-    """{scope: seconds} of ``ops`` by their own time."""
+def scope_table(ops, names, scopes):
+    """{scope: seconds} of ``ops`` by their own time, named by ``scopes``."""
     out = defaultdict(float)
     for op, ns in exclusive_ns(ops).items():
-        out[scope_of(names.get(op.lstrip("%")))] += ns / 1e9
+        out[scope_of(names.get(op.lstrip("%")), scopes)] += ns / 1e9
     return dict(sorted(out.items(), key=lambda kv: -kv[1]))
 
 
@@ -172,7 +173,9 @@ def reduce(run, names):
                     for a, b, parts in longest]}
     out["after_restore"] = after_restores(inside, ops)
     if names:
-        out["scopes"] = {"train_step_s": scope_table(module_ops, names),
+        scopes = SCOPES + common.arch(run.config).SCOPES
+        out["scopes"] = {"train_step_s": scope_table(module_ops, names,
+                                                     scopes),
                          "other_programs_s": sum(
                              exclusive_ns(ops).values()) / 1e9
                          - sum(exclusive_ns(module_ops).values()) / 1e9}

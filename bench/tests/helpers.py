@@ -47,15 +47,18 @@ UNLISTED = {"serve_closed": {
                   _metric("serve_mfu", "%", "higher", "serve_tokens_per_s")]}}
 
 
-def tiny_root(tmp, traffic, *, mix=None, limits=None, cell_traffic=None):
+def tiny_root(tmp, traffic, *, mix=None, limits=None, cell_traffic=None,
+              config=None):
     """Write a checkout under ``tmp`` whose cell ``tiny.<cell_traffic>``
-    runs the driver of ``traffic`` at a tiny size.  Returns (spec, name)."""
+    runs the driver of ``traffic`` at a tiny size; ``config`` changes keys
+    of the tiny configuration.  Returns (spec, name)."""
     tmp = Path(tmp)
     cell_traffic = cell_traffic or traffic
     for d in ("configs", "workloads", "limits"):
         (tmp / "bench" / d).mkdir(parents=True, exist_ok=True)
     cfg = common.load_json(common.BENCH / "configs" / "granite-3-2b-d6.json")
     cfg.update(TINY)
+    cfg.update(config or {})
     (tmp / "bench/configs/tiny.json").write_text(json.dumps(cfg))
     m = common.load_json(common.BENCH / "workloads" / f"{traffic}.json")
     m.update(SMALL[traffic])

@@ -1,9 +1,10 @@
-"""The harness's own pieces, on the CPU: finding a cell's files by name,
-the trace reduction, the model-FLOP count, the peaks table, the seeds, the
+"""The harness's own pieces, on the CPU: finding a cell's files and its
+architecture by name, the trace reduction, the model-FLOP count, the peaks table, the seeds, the
 weights, and the refusal to run without a TPU."""
 from __future__ import annotations
 
 import json
+import shutil
 
 import numpy as np
 import pytest
@@ -33,6 +34,33 @@ def test_a_new_traffic_file_alone_defines_a_cell_that_runs(tmp_path):
     assert res["metrics"]["train_tokens_per_s"]["value"] > 0
     assert "tiny.train_new" not in json.dumps(
         common.load_json(common.ROOT / "BENCHMARK.json"))
+
+
+def test_a_new_architecture_file_alone_defines_a_cell_that_runs(
+        tmp_path, monkeypatch):
+    """A cell whose configuration names an architecture that only a new
+    file in ``bench/archs/`` defines (here the dense block with biases on
+    its query, key and value projections) runs through the unchanged
+    harness and drivers, and its reference holds the program to it."""
+    archs = tmp_path / "bench" / "archs"
+    archs.mkdir(parents=True)
+    for f in common.ARCHS.glob("*.py"):
+        shutil.copy(f, archs)
+    shutil.copy(common.BENCH / "tests" / "archs" / "dense_qkv_bias.py", archs)
+    monkeypatch.setattr(common, "ARCHS", archs)
+    # the cell's own change_gap limit, set as the cells' are, from CPU
+    # readings over ten seeds: the program 1.2e-3 to 1.1e-2 (its worst leaf
+    # is the key's bias, whose gradient largely cancels over positions in
+    # bfloat16), half the batch 0.22-0.26; the float8 control reads 3.9e-3
+    # to 5.9e-3 here and fails grad_gap (1.5e-2 to 2.1e-2) and loss_gap
+    res = helpers.run_tiny(tmp_path, "train_ckpt", limits={"change_gap": 0.04},
+                           config={"model_type": "dense_qkv_bias",
+                                   "attention_bias": True})
+    assert res["correct"], res["checks"]
+    assert res["metrics"]["train_tokens_per_s"]["value"] > 0
+    spec = json.dumps(common.load_json(common.ROOT / "BENCHMARK.json"))
+    assert "dense_qkv_bias" not in spec
+    assert not (common.BENCH / "archs" / "dense_qkv_bias.py").exists()
 
 
 def test_unknown_cell_and_unknown_device_are_refused():
@@ -74,14 +102,15 @@ def test_trace_reduction_on_a_recorded_trace():
 
 def test_model_flops_match_a_hand_count_for_d6():
     cfg = common.load_json(common.BENCH / "configs" / "granite-3-2b-d6.json")
+    arch = common.arch(cfg)
     d, f, v = 2048, 8192, 49155
     per_layer = d * 2048 + 2 * d * 512 + 2048 * d + 3 * d * f
-    assert flops.layer_matmul_params(cfg) == per_layer == 60_817_408
+    assert arch.layer_matmul_params(cfg) == per_layer == 60_817_408
     matmul = 2 * 4 * 2048 * (6 * per_layer + d * v)
     attn = 4 * 2048 * (2048 * 2049 // 2) * 6 * 4
-    assert flops.train_step_flops(cfg, 4, 2048) == 3 * (matmul + attn)
-    assert flops.decode_flops(cfg, 99) == (2 * (6 * per_layer + d * v)
-                                           + 4 * 2048 * 100 * 6)
+    assert arch.train_step_flops(cfg, 4, 2048) == 3 * (matmul + attn)
+    assert arch.decode_flops(cfg, 99) == (2 * (6 * per_layer + d * v)
+                                          + 4 * 2048 * 100 * 6)
 
 
 def test_seeds_of_any_size_differ():

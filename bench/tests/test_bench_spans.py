@@ -113,8 +113,20 @@ def test_span_report_counts_each_operation_by_its_own_time():
     assert names == {"fusion.2": "jit(step)/transpose(jvp(attention))/"
                                  "dot_general",
                      "fusion.3": "jit(step)/loss/head/dot_general"}
-    assert span_report.scope_table(ops, names) == {
+    assert span_report.scope_table(ops, names, span_report.SCOPES) == {
         "other": 40e-9, "head": 40e-9, "attention": 30e-9}
+
+
+def test_an_architectures_own_scope_is_counted_under_its_name():
+    from bench import span_report
+    op = "jit(train_step)/while/body/transpose(jvp(mamba))/mixer/dot_general"
+    assert span_report.scope_of(op, span_report.SCOPES) == "other"
+    scopes = span_report.SCOPES + ("mamba",)
+    assert span_report.scope_of(op, scopes) == "mamba"
+    assert span_report.scope_of("jit(step)/mamba/attention/x", scopes) == (
+        "attention")
+    ops, names = [("%fusion.1", 0, 70)], {"fusion.1": op}
+    assert span_report.scope_table(ops, names, scopes) == {"mamba": 70e-9}
 
 
 def test_span_report_times_the_first_step_after_each_restore():

@@ -1,43 +1,17 @@
 """Weights from the seed, made on the device in one jitted call, in the
 layout and the type the program serves them in.
 
-The leaf order, the initialisers and their scales follow the program's
-parameter tree for a dense decoder (embed, final_norm, head, then one
-stacked segment of layers: attn wk wo wq wv, ffn wg wi wo, ln1, ln2), each
-leaf drawn from ``fold_in(key, leaf index)``.  The benchmark keeps its own
-copy so that the reference can make the same weights again without
-importing the program; a driver checks that the program's tree has exactly
-these shapes before it hands the weights over.
+The leaf order, the initialisers and their scales are the architecture's
+``leaf_specs`` (``bench/archs/<model_type>.py``), which follow the
+program's parameter tree; each leaf is drawn from ``fold_in(key, leaf
+index)``.  The benchmark keeps its own copy so that the reference can make
+the same weights again without importing the program; a driver checks that
+the program's tree has exactly these shapes before it hands the weights
+over.
 """
 from __future__ import annotations
 
-import numpy as np
-
-
-def leaf_specs(config):
-    """[(path, shape, init, scale)] in the program's flatten order."""
-    d, f = config["hidden_size"], config["intermediate_size"]
-    H, K, hd = (config["num_attention_heads"], config["num_key_value_heads"],
-                config["head_dim"])
-    L, Vp = config["num_hidden_layers"], config["padded_vocab"]
-
-    def fan(n):
-        return np.float64(1.0) / np.sqrt(n)
-
-    return [
-        ("embed", (Vp, d), "embed", 0.02),
-        ("final_norm", (d,), "ones", None),
-        ("head", (d, Vp), "normal", fan(d)),
-        ("segments.0.attn.wk", (L, d, K * hd), "normal", fan(d)),
-        ("segments.0.attn.wo", (L, H * hd, d), "normal", fan(H * hd)),
-        ("segments.0.attn.wq", (L, d, H * hd), "normal", fan(d)),
-        ("segments.0.attn.wv", (L, d, K * hd), "normal", fan(d)),
-        ("segments.0.ffn.wg", (L, d, f), "normal", fan(d)),
-        ("segments.0.ffn.wi", (L, d, f), "normal", fan(d)),
-        ("segments.0.ffn.wo", (L, f, d), "normal", fan(f)),
-        ("segments.0.ln1", (L, d), "ones", None),
-        ("segments.0.ln2", (L, d), "ones", None),
-    ]
+from bench import common
 
 
 def _nest(flat):
@@ -65,7 +39,7 @@ def make(config, seed, dtype=None):
     import jax
     import jax.numpy as jnp
     dt = jnp.dtype(dtype or config["torch_dtype"])
-    specs = leaf_specs(config)
+    specs = common.arch(config).leaf_specs(config)
 
     def build(key):
         out = {}
@@ -82,10 +56,11 @@ def make(config, seed, dtype=None):
 
 
 def check_layout(config, abstract_tree):
-    """Refuse a program whose parameter tree differs from ``leaf_specs``."""
+    """Refuse a program whose parameter tree differs from the
+    architecture's ``leaf_specs``."""
     import jax
     leaves = jax.tree.leaves(abstract_tree)
-    want = [s[1] for s in leaf_specs(config)]
+    want = [s[1] for s in common.arch(config).leaf_specs(config)]
     got = [tuple(x.shape) for x in leaves]
     if got != [tuple(w) for w in want]:
         raise ValueError(f"the program's parameter leaves {got} differ from "
