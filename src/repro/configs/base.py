@@ -36,12 +36,20 @@ class MLAConfig:
 
 @dataclass(frozen=True)
 class SSMConfig:
-    """SSD / Mamba-2-style mixer (scalar per-head decay, chunked GLA form)."""
+    """SSD / Mamba-2-style mixer (scalar per-head decay, chunked GLA form).
+
+    ``n_groups`` and ``conv_bias`` are read by the Mamba-2 mixer of
+    ``layer_types`` "mamba" layers (``ssm.mamba2_apply``): B and C per group
+    of heads, a bias on the causal conv, and the gated norm taken over each
+    group's width after the gate.  Hymba's parallel heads (``ssd_apply``)
+    share one B/C, have no conv bias and normalise each head before it."""
     d_state: int = 16
     d_conv: int = 4
     n_ssm_heads: int = 8
     head_dim: int = 64
     chunk: int = 256
+    n_groups: int = 1
+    conv_bias: bool = False
 
 
 @dataclass(frozen=True)
@@ -68,6 +76,18 @@ class ModelConfig:
     rope_theta: float = 1_000_000.0
     norm_eps: float = 1e-5
     block: str = "attn"               # attn | xlstm | hymba
+    # per-layer mixer, "mamba" (Mamba-2) or "attention", one entry a layer;
+    # empty: every layer is a ``block``
+    layer_types: tuple = ()
+    position_embedding: str = "rope"  # rope | nope (no positions in attention)
+    # Granite's multipliers: the embedding's output is scaled by
+    # embedding_multiplier, attention scores by attention_multiplier (None:
+    # 1/sqrt(head_dim)), each sub-block's output by residual_multiplier
+    # before it is added, and the logits divided by logits_scaling
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     window: Optional[int] = None      # sliding-window size (None = full attention)
     global_layers: tuple = ()         # layer indices with full attention (hymba)
     moe: Optional[MoEConfig] = None
@@ -132,6 +152,10 @@ class ModelConfig:
             n += (L // 2) * (per_m + per_s)
             return n
         for i in range(L):
+            if self.layer_types and self.layer_types[i] == "mamba":
+                n += self.mamba_param_count()
+                n += 3 * d * self.d_ff
+                continue
             attn = d * self.n_heads * hd  # q
             attn += 2 * d * self.kv_cache_width if self.mla is None else 0
             if self.mla is not None:
@@ -155,6 +179,14 @@ class ModelConfig:
             elif self.d_ff:
                 n += 3 * d * self.d_ff
         return n
+
+    def mamba_param_count(self) -> int:
+        """Parameters of one Mamba-2 mixer (``ssm.mamba2_specs``)."""
+        s, d = self.ssm, self.d_model
+        di, gn = s.n_ssm_heads * s.head_dim, 2 * s.n_groups * s.d_state
+        n = 2 * d * di + d * gn + d * s.n_ssm_heads      # z, x, B/C, dt
+        n += s.d_conv * (di + gn) + (di + gn if s.conv_bias else 0)
+        return n + 3 * s.n_ssm_heads + di + di * d      # dt_bias, A, D; norm; out
 
     def active_param_count(self) -> int:
         """Active params per token (MoE: only top_k experts) for 6*N_active*D."""
@@ -219,6 +251,7 @@ ARCH_IDS = [
     "minicpm-2b",
     "granite-3-2b",
     "musicgen-large",
+    "granite-4.0-h-micro",
 ]
 
 _MOD = {a: a.replace("-", "_").replace(".", "_") for a in ARCH_IDS}
@@ -267,7 +300,13 @@ def smoke_config(name: str) -> ModelConfig:
     if cfg.block == "xlstm":
         kw["xlstm"] = XLSTMConfig(n_heads=2, chunk=8)
     if cfg.ssm is not None:
-        kw["ssm"] = SSMConfig(d_state=8, d_conv=4, n_ssm_heads=2, head_dim=32, chunk=8)
+        kw["ssm"] = replace(cfg.ssm, d_state=8, d_conv=4, n_ssm_heads=2,
+                            head_dim=32, chunk=8)
+    if cfg.layer_types:
+        # every kind of layer of the pattern, the first kind twice
+        kinds = tuple(dict.fromkeys(cfg.layer_types))
+        kw["layer_types"] = (kinds + kinds[:1])[:3]
+        kw["n_layers"] = len(kw["layer_types"])
     if cfg.moe is not None:
         # capacity_factor 8 => no token drops at smoke scale, so the prefill
         # (capacity-dispatch) and decode (gather) paths agree exactly

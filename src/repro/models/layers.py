@@ -90,12 +90,13 @@ def _chunk_bounds(i, qc, kc, nk, schedule, window):
 
 
 def chunked_attention(ctx, q, k, v, *, window=None, schedule="masked",
-                      q_chunk=1024, kv_chunk=2048, pos_offset=0):
+                      q_chunk=1024, kv_chunk=2048, pos_offset=0, scale=None):
     """Memory-efficient causal attention with online softmax.
 
     q: [B,S,H,D]; k, v: [B,S,H,D] (caller repeats GQA kv heads to H).
     `schedule='masked'` scans every KV chunk with a mask (paper-faithful baseline);
     `'triangular'` statically skips chunks above the diagonal / outside the window.
+    Scores are scaled by `scale`, by default 1/sqrt(D).
     """
     B, S, H, D = q.shape
     dt = q.dtype
@@ -106,7 +107,8 @@ def chunked_attention(ctx, q, k, v, *, window=None, schedule="masked",
     while S % kc:
         kc //= 2
     nq, nk = S // qc, S // kc
-    scale = 1.0 / math.sqrt(D)
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
 
     q = ctx.act(q, "act_batch", None, "act_heads", None)
     k = ctx.act(k, "act_batch", None, "act_heads", None)
@@ -157,10 +159,12 @@ def chunked_attention(ctx, q, k, v, *, window=None, schedule="masked",
 # decode attention: split-KV flash-decoding, manual SPMD over the cache seq dim
 # ---------------------------------------------------------------------------
 
-def _attn_partials(q, k, v, kpos, total_len, window):
+def _attn_partials(q, k, v, kpos, total_len, window, scale=None):
     """q: [B,K,G,Dk]; k: [B,Sl,K,Dk]; v: [B,Sl,K,Dv]; kpos: [Sl] global positions.
-    Returns unnormalized (m, l, o) partials for a cache shard."""
-    scale = 1.0 / math.sqrt(q.shape[-1])
+    Returns unnormalized (m, l, o) partials for a cache shard; scores are
+    scaled by `scale`, by default 1/sqrt(Dk)."""
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
     s = jnp.einsum("bkgd,bskd->bkgs", q.astype(jnp.float32) * scale,
                    k.astype(jnp.float32))
     valid = kpos < total_len
@@ -187,7 +191,7 @@ def _local_row_update(cache, row, pos, offset):
 
 
 def decode_attention(ctx, q, k_cache, v_cache, k_new, v_new, pos, *, n_kv_heads,
-                     window=None, v_dim=None):
+                     window=None, v_dim=None, scale=None):
     """One-token attention over a (possibly huge) cache, with the cache-row write
     performed inside the shard_map (so a sequence-sharded cache is never gathered).
 
@@ -222,7 +226,7 @@ def decode_attention(ctx, q, k_cache, v_cache, k_new, v_new, pos, *, n_kv_heads,
         k4 = kx.reshape(kx.shape[0], kx.shape[1], K, Dk).astype(jnp.float32)
         v4 = vx[..., : K * Dv].reshape(vx.shape[0], vx.shape[1], K, Dv).astype(jnp.float32)
         kpos = offset + jnp.arange(kx.shape[1])
-        m, l, o = _attn_partials(qx, k4, v4, kpos, tpos + 1, window)
+        m, l, o = _attn_partials(qx, k4, v4, kpos, tpos + 1, window, scale)
         if seq_axes:
             m_g = jax.lax.pmax(m, seq_axes)
             corr = jnp.exp(m - m_g)
@@ -252,7 +256,8 @@ def ring_slot_positions(pos, W):
     return pos - jnp.mod(pos - slots, W)
 
 
-def window_decode_attention(q, k_cache, v_cache, pos, *, n_kv_heads, window):
+def window_decode_attention(q, k_cache, v_cache, pos, *, n_kv_heads, window,
+                            scale=None):
     """Decode attention over a ring-buffer window cache [B, W, K*D]."""
     B, W, KD = k_cache.shape
     K = n_kv_heads
@@ -264,7 +269,8 @@ def window_decode_attention(q, k_cache, v_cache, pos, *, n_kv_heads, window):
     k4 = k_cache.reshape(B, W, K, D).astype(jnp.float32)
     v4 = v_cache.reshape(B, W, K, D).astype(jnp.float32)
     valid = (kpos >= 0) & (kpos >= pos + 1 - window)
-    m, l, o = _attn_partials(q4, k4, v4, jnp.where(valid, kpos, pos + 1), pos + 1, None)
+    m, l, o = _attn_partials(q4, k4, v4, jnp.where(valid, kpos, pos + 1), pos + 1,
+                             None, scale)
     out = o / jnp.maximum(l, 1e-30)[..., None]
     return out.reshape(B, H * D).astype(q.dtype)
 
@@ -292,7 +298,9 @@ def attn_specs(cfg):
 @jax.named_scope("attention")
 def attn_apply(ctx, cfg, p, x, *, mode, window=None, cache=None, pos=None,
                use_ring=False):
-    """x: [B,S,d] (train/prefill) or [B,d] (decode).
+    """x: [B,S,d] (train/prefill) or [B,d] (decode).  Rotary positions
+    unless ``cfg.position_embedding`` is "nope"; scores scaled by
+    ``cfg.attention_multiplier`` (None: 1/sqrt(head_dim)).
     Returns (out, new_cache). Cache layout:
       full:  {'k': [B,S_max,K*hd], 'v': ...}   (written at absolute positions)
       ring:  {'k': [B,W,K*hd], 'v': ...}       (sliding-window ring buffer)
@@ -301,6 +309,8 @@ def attn_apply(ctx, cfg, p, x, *, mode, window=None, cache=None, pos=None,
     H, K = cfg.n_heads, cfg.n_kv_heads
     G = H // K
     theta = cfg.rope_theta
+    nope = cfg.position_embedding == "nope"
+    scale = cfg.attention_multiplier
 
     if mode in ("train", "prefill"):
         B, S, _ = x.shape
@@ -310,14 +320,16 @@ def attn_apply(ctx, cfg, p, x, *, mode, window=None, cache=None, pos=None,
         v = jnp.einsum("bsd,df->bsf", x, p["wv"])
         if cfg.qkv_bias:
             q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-        q = rope(q.reshape(B, S, H, hd), positions, theta)
-        k = rope(k.reshape(B, S, K, hd), positions, theta)
+        place = (lambda t: t) if nope else (lambda t: rope(t, positions, theta))
+        q = place(q.reshape(B, S, H, hd))
+        k = place(k.reshape(B, S, K, hd))
         v = v.reshape(B, S, K, hd)
         kr = jnp.repeat(k, G, axis=2)
         vr = jnp.repeat(v, G, axis=2)
         o = chunked_attention(ctx, q, kr, vr, window=window,
                               schedule=cfg.attn_schedule,
-                              q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk)
+                              q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
+                              scale=scale)
         out = jnp.einsum("bsf,fd->bsd", o.reshape(B, S, H * hd), p["wo"])
         new_cache = None
         if mode == "prefill":
@@ -345,19 +357,21 @@ def attn_apply(ctx, cfg, p, x, *, mode, window=None, cache=None, pos=None,
     v = jnp.einsum("bd,df->bf", x, p["wv"])
     if cfg.qkv_bias:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    posv = jnp.full((B,), pos)
-    q = rope(q.reshape(B, H, hd), posv, theta).reshape(B, H * hd)
-    k = rope(k.reshape(B, K, hd), posv, theta).reshape(B, K * hd)
+    if not nope:
+        posv = jnp.full((B,), pos)
+        q = rope(q.reshape(B, H, hd), posv, theta).reshape(B, H * hd)
+        k = rope(k.reshape(B, K, hd), posv, theta).reshape(B, K * hd)
     cdt = cache["k"].dtype
     if use_ring:
         W = cache["k"].shape[1]
         slot = jnp.mod(pos, W)
         kc = jax.lax.dynamic_update_slice(cache["k"], k.astype(cdt)[:, None], (0, slot, 0))
         vc = jax.lax.dynamic_update_slice(cache["v"], v.astype(cdt)[:, None], (0, slot, 0))
-        o = window_decode_attention(q, kc, vc, pos, n_kv_heads=K, window=window)
+        o = window_decode_attention(q, kc, vc, pos, n_kv_heads=K, window=window,
+                                    scale=scale)
     else:
         o, kc, vc = decode_attention(ctx, q, cache["k"], cache["v"], k, v, pos,
-                                     n_kv_heads=K, window=window)
+                                     n_kv_heads=K, window=window, scale=scale)
     out = jnp.einsum("bf,fd->bd", o, p["wo"])
     return out, {"k": kc, "v": vc}
 

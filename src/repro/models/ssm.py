@@ -1,5 +1,6 @@
-"""Chunked gated linear recurrences: the shared math for SSD (Mamba-2, used by
-Hymba's parallel SSM heads) and the stabilized mLSTM (xLSTM).
+"""Chunked gated linear recurrences: the shared math for SSD (Mamba-2: Hymba's
+parallel SSM heads and the Mamba-2 mixer of hybrid layer patterns) and the
+stabilized mLSTM (xLSTM).
 
 Conventions: q/k are the "read/write" vectors (C_t/B_t for SSD), v the values,
 `lg` per-head log decay gates. All recurrences are validated against naive
@@ -43,7 +44,9 @@ def chunked_gla(q, k, v, lg, chunk=256):
         s = jnp.einsum("bihn,bjhn->bhij", qc, kc)
         dec = cumc.transpose(0, 2, 1)[:, :, :, None] - cumc.transpose(0, 2, 1)[:, :, None, :]
         mask = jnp.tril(jnp.ones((qc.shape[1], qc.shape[1]), bool))
-        w = jnp.where(mask[None, None], jnp.exp(dec), 0.0)
+        # masked before the exp: above the diagonal dec grows with the
+        # chunk's total decay, and exp(dec) = inf would make the gradient nan
+        w = jnp.exp(jnp.where(mask[None, None], dec, -jnp.inf))
         y_intra = jnp.einsum("bhij,bjhp->bihp", s * w, vc)
         # contributions for the carried state
         kdec = jnp.exp(totc[:, None, :] - cumc)          # [B,c,H]
@@ -232,3 +235,93 @@ def ssd_apply(ctx, cfg, p, x, *, mode, cache=None):
     y = rms_groupnorm(y.reshape(B, dss), p["norm"], Hs)
     y = y * jax.nn.silu(z)
     return y @ p["wo"], {"state": state, "conv": conv_state}
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2 mixer (the "mamba" layers of a layer pattern, as published)
+# ---------------------------------------------------------------------------
+
+def mamba2_specs(cfg):
+    """In-projections to z, x, B/C (one pair per group) and dt; causal conv
+    over x and over B/C, with a bias where ``ssm.conv_bias``; per-head
+    dt bias, A (as log) and D; the gated norm's weight; the out-projection.
+    x, z and the norm follow the heads ('inner'); B/C are every head's."""
+    s, d = cfg.ssm, cfg.d_model
+    H, W = s.n_ssm_heads, s.d_conv
+    di, gn = H * s.head_dim, 2 * s.n_groups * s.d_state
+    sp = {
+        "w_z": ParamSpec((d, di), ("embed", "inner")),
+        "w_x": ParamSpec((d, di), ("embed", "inner")),
+        "w_bc": ParamSpec((d, gn), ("embed", None)),
+        "w_dt": ParamSpec((d, H), ("embed", "ssm_heads")),
+        "conv_x": ParamSpec((W, di), ("conv", "inner"), scale=0.5),
+        "conv_bc": ParamSpec((W, gn), ("conv", None), scale=0.5),
+        "dt_bias": ParamSpec((H,), ("ssm_heads",), scale=1.0),
+        "a_log": ParamSpec((H,), ("ssm_heads",), scale=1.0),
+        "d_skip": ParamSpec((H,), ("ssm_heads",), init="ones"),
+        "norm": ParamSpec((di,), ("inner",), init="ones"),
+        "wo": ParamSpec((di, d), ("inner", "embed")),
+    }
+    if s.conv_bias:
+        sp["conv_x_bias"] = ParamSpec((di,), ("inner",), scale=0.1)
+        sp["conv_bc_bias"] = ParamSpec((gn,), (None,), scale=0.1)
+    return sp
+
+
+def _conv_act(p, name, x, state=None):
+    """SiLU of the causal conv (plus its bias) over the sequence, or of
+    one decode step given the last W-1 inputs, in float32."""
+    f32 = jnp.float32
+    w = p[name].astype(f32)
+    if state is None:
+        y, new_state = causal_conv1d(x.astype(f32), w), None
+    else:
+        y, new_state = causal_conv1d_step(x.astype(f32), state, w)
+    if name + "_bias" in p:
+        y = y + p[name + "_bias"].astype(f32)
+    return jax.nn.silu(y), new_state
+
+
+@jax.named_scope("mamba")
+def mamba2_apply(ctx, cfg, p, x, *, mode, cache=None):
+    """x: [B,S,d] (train/prefill) or [B,d] (decode).  The Mamba-2 mixer:
+    dt = softplus(x w_dt + dt_bias), A = -exp(a_log); per head
+    h_t = exp(dt_t A) h_{t-1} + dt_t B_t x_t^T and y_t = C_t h_t + D x_t
+    (B, C of the head's group), by the chunked form; then the gate,
+    y * silu(z), and an RMS norm over each group's width.
+    cache: {'state': [B,H,N,P], 'conv_x': [B,W-1,di], 'conv_bc': [B,W-1,2GN]}."""
+    s = cfg.ssm
+    H, P, G, N = s.n_ssm_heads, s.head_dim, s.n_groups, s.d_state
+    f32 = jnp.float32
+    decode = mode == "decode"
+    lead = x.shape[:-1]
+    z = x @ p["w_z"]
+    xs, bc = x @ p["w_x"], x @ p["w_bc"]
+    dt = jax.nn.softplus((x @ p["w_dt"]).astype(f32) + p["dt_bias"].astype(f32))
+    new_cache = None
+    if decode:
+        xs_act, cx = _conv_act(p, "conv_x", xs, cache["conv_x"])
+        bc_act, cbc = _conv_act(p, "conv_bc", bc, cache["conv_bc"])
+    else:
+        xs_act, _ = _conv_act(p, "conv_x", xs)
+        bc_act, _ = _conv_act(p, "conv_bc", bc)
+    xh = xs_act.reshape(*lead, H, P)
+    with jax.named_scope("ssd"):
+        a = -jnp.exp(p["a_log"].astype(f32))
+        bt, ct = (jnp.repeat(t.reshape(*lead, G, N), H // G, axis=-2)
+                  for t in (bc_act[..., :G * N], bc_act[..., G * N:]))
+        v, lg = xh * dt[..., None], dt * a
+        if decode:
+            y, state = gla_step(ct, bt, v, lg, cache["state"])
+        else:
+            y, state = chunked_gla(ct, bt, v, lg, chunk=s.chunk)
+    y = y + xh * p["d_skip"].astype(f32)[:, None]
+    y = y.reshape(*lead, H * P) * jax.nn.silu(z.astype(f32))
+    y = rms_groupnorm(y, p["norm"], G, cfg.norm_eps).astype(x.dtype)
+    if decode:
+        new_cache = {"state": state, "conv_x": cx, "conv_bc": cbc}
+    elif mode == "prefill":
+        W = s.d_conv
+        new_cache = {"state": state, "conv_x": xs[:, -(W - 1):],
+                     "conv_bc": bc[:, -(W - 1):]}
+    return y @ p["wo"], new_cache

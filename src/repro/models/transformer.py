@@ -1,6 +1,11 @@
 """Decoder-stack assembly: per-family block definitions, segment planning
 (scanned homogeneous runs + unscanned exceptional layers), embeddings, heads.
 
+A ``layer_types`` pattern (hybrid models) gives each layer its mixer: each
+run of "mamba" layers is one scanned segment, each "attention" layer a
+segment of its own.  Granite's multipliers scale the embedding, every
+sub-block's output before its residual add, and the logits.
+
 Segments keep compile time bounded at 512-way SPMD: a 60-layer dense model is a
 single `lax.scan` over stacked params with (optionally) a remat'd body.
 """
@@ -23,13 +28,26 @@ VISION_DIM = 1024  # stubbed llava frontend output width
 
 @dataclass(frozen=True)
 class Segment:
-    kind: str            # 'attn' | 'hymba' | 'xlstm_pair'
+    kind: str            # 'attn' | 'hymba' | 'xlstm_pair' | 'mamba'
     n: int               # number of block repetitions in this segment
     scanned: bool
     window: Optional[int]  # None = full attention
 
 
 def plan_segments(cfg):
+    if cfg.layer_types:
+        assert len(cfg.layer_types) == cfg.n_layers, cfg.layer_types
+        segs = []
+        for kind in cfg.layer_types:
+            if kind == "attention":
+                segs.append(Segment("attn", 1, False, cfg.window))
+            elif kind != "mamba":
+                raise ValueError(f"unknown layer type {kind!r}")
+            elif segs and segs[-1].kind == "mamba":
+                segs[-1] = Segment("mamba", segs[-1].n + 1, True, None)
+            else:
+                segs.append(Segment("mamba", 1, True, None))
+        return segs
     if cfg.block == "xlstm":
         assert cfg.n_layers % 2 == 0
         return [Segment("xlstm_pair", cfg.n_layers // 2, True, None)]
@@ -61,7 +79,10 @@ def block_specs(cfg, kind):
             "slstm": X.slstm_specs(cfg),
         }
     sp = {"ln1": ParamSpec((d,), ("embed",), init="ones")}
-    sp["attn"] = L.mla_specs(cfg) if cfg.mla is not None else L.attn_specs(cfg)
+    if kind == "mamba":
+        sp["mamba"] = S.mamba2_specs(cfg)
+    else:
+        sp["attn"] = L.mla_specs(cfg) if cfg.mla is not None else L.attn_specs(cfg)
     if kind == "hymba":
         sp["ssd"] = S.ssd_specs(cfg)
     if cfg.moe is not None:
@@ -71,6 +92,13 @@ def block_specs(cfg, kind):
         sp["ln2"] = ParamSpec((d,), ("embed",), init="ones")
         sp["ffn"] = L.mlp_specs(cfg)
     return sp
+
+
+def _residual(cfg, x, out):
+    """x plus a sub-block's output, scaled by the residual multiplier."""
+    if cfg.residual_multiplier != 1.0:
+        out = out * cfg.residual_multiplier
+    return x + out
 
 
 def block_apply(ctx, cfg, kind, p, x, *, mode, window, cache=None, pos=None):
@@ -88,7 +116,11 @@ def block_apply(ctx, cfg, kind, p, x, *, mode, window, cache=None, pos=None):
 
     xn = L.rmsnorm(x, p["ln1"])
     use_ring = window is not None
-    if cfg.mla is not None:
+    if kind == "mamba":
+        a_out, a_cache = S.mamba2_apply(
+            ctx, cfg, p["mamba"], xn, mode=mode,
+            cache=None if cache is None else cache["mamba"])
+    elif cfg.mla is not None:
         a_out, a_cache = L.mla_apply(ctx, cfg, p["attn"], xn, mode=mode,
                                      cache=None if cache is None else cache["attn"],
                                      pos=pos)
@@ -100,10 +132,10 @@ def block_apply(ctx, cfg, kind, p, x, *, mode, window, cache=None, pos=None):
     if kind == "hymba":
         s_out, s_cache = S.ssd_apply(ctx, cfg, p["ssd"], xn, mode=mode,
                                      cache=None if cache is None else cache["ssd"])
-        x = x + 0.5 * (a_out + s_out)
+        x = _residual(cfg, x, 0.5 * (a_out + s_out))
     else:
         s_cache = None
-        x = x + a_out
+        x = _residual(cfg, x, a_out)
 
     if "ffn" in p:
         xn2 = L.rmsnorm(x, p["ln2"])
@@ -112,10 +144,12 @@ def block_apply(ctx, cfg, kind, p, x, *, mode, window, cache=None, pos=None):
             aux = aux + moe_aux
         else:
             f_out = L.mlp_apply(ctx, p["ffn"], xn2)
-        x = x + f_out
+        x = _residual(cfg, x, f_out)
 
     nc = None
-    if a_cache is not None or s_cache is not None:
+    if a_cache is not None and kind == "mamba":
+        nc = {"mamba": a_cache}
+    elif a_cache is not None or s_cache is not None:
         nc = {"attn": a_cache}
         if kind == "hymba":
             nc["ssd"] = s_cache
@@ -141,7 +175,10 @@ def model_specs(cfg):
         bs = block_specs(cfg, seg.kind)
         sp["segments"].append(stack_spec(bs, seg.n) if seg.scanned else bs)
     sp["final_norm"] = ParamSpec((d,), ("embed",), init="ones")
-    sp["head"] = ParamSpec((d, cfg.n_codebooks * Vp), ("embed", "vocab"))
+    if cfg.tie_embeddings:
+        assert cfg.n_codebooks == 1, "tied embeddings need one codebook"
+    else:
+        sp["head"] = ParamSpec((d, cfg.n_codebooks * Vp), ("embed", "vocab"))
     return sp
 
 
@@ -160,14 +197,24 @@ def embed_tokens(ctx, cfg, params, tokens, patch_embeds=None):
         if h.ndim == 3:
             h = jnp.concatenate([vis, h[:, cfg.img_tokens:]], axis=1)
     h = h.astype(jnp.dtype(cfg.compute_dtype))
+    if cfg.embedding_multiplier != 1.0:
+        h = h * jnp.asarray(cfg.embedding_multiplier, h.dtype)
     axes = ("act_batch",) + (None,) * (h.ndim - 1)
     return ctx.act(h, *axes)
 
 
 @jax.named_scope("head")
 def lm_head(ctx, cfg, params, h):
-    """h: [..., d] -> logits [..., n_codebooks * padded_vocab] (f32)."""
-    logits = jnp.einsum("...d,dv->...v", h, params["head"]).astype(jnp.float32)
+    """h: [..., d] -> logits [..., n_codebooks * padded_vocab] (f32), over
+    the embedding's rows where the embeddings are tied, divided by the
+    logits scaling."""
+    if cfg.tie_embeddings:
+        logits = jnp.einsum("...d,vd->...v", h, params["embed"])
+    else:
+        logits = jnp.einsum("...d,dv->...v", h, params["head"])
+    logits = logits.astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     if h.ndim == 3:
         logits = ctx.act(logits, "act_batch", None, "act_vocab")
     else:

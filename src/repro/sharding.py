@@ -4,7 +4,8 @@ EXPERIMENTS.md §Perf iterates on these tables.
 Logical axes used by the model zoo:
   params: 'embed' (d_model / reduction dim), 'heads' (fused q heads), 'kv' (fused kv),
           'mlp' (d_ff), 'vocab', 'expert', 'expert_in', 'expert_mlp', 'lora', 'conv',
-          'inner' (xlstm/ssm inner width), 'layers' (scanned stack)
+          'inner' (xlstm/ssm inner width), 'ssm_heads' (Mamba-2 per-head),
+          'layers' (scanned stack)
   acts:   'act_batch', 'act_seq', 'act_embed', 'act_heads', 'act_kv_seq', 'act_expert',
           'act_vocab'
 """
@@ -33,6 +34,7 @@ TRAIN_RULES = {
     "lora": None,
     "inner": "model",
     "inner_in": "data",
+    "ssm_heads": "model",     # per-head leaves of the Mamba-2 mixer (dt, A, D)
     "conv": None,
     "layers": None,
     "act_batch": ("pod", "data"),
